@@ -91,31 +91,6 @@ BENCH_JSON = "BENCH_10.json"
 ARCH = "mamba2-370m"          # constant-state decode: the serving workhorse
 
 
-def _publish_serve_app(ws, arch: str):
-    """Publish the weights bundle + app for ``arch`` (smoke config)."""
-    from repro import models
-    from repro.ckpt import bundle_from_params
-    from repro.configs import get_config
-    from repro.core import ObjectKind, make_object
-
-    cfg = get_config(arch, smoke=True)
-    params = {
-        n: np.asarray(v) for n, v in models.init_params(cfg, 0).items()
-    }
-    bundle, payload = bundle_from_params(f"weights:{cfg.name}", "v1", params)
-    app, _ = make_object(
-        name=f"serve:{cfg.name}",
-        version="1",
-        kind=ObjectKind.APPLICATION,
-        refs=models.manifest_refs(cfg),
-        needed=[bundle.name],
-    )
-    with ws.management() as tx:
-        tx.publish(bundle, payload)
-        tx.publish(app)
-    return cfg, app.name
-
-
 def _image_digest(image) -> str:
     """Same digest the traffic workers report in their ADOPTED frames:
     blake2b-16 over every tensor's contiguous bytes, in sorted name order."""
@@ -157,6 +132,7 @@ def _bench_generate_sync_fix(cfg, ws, app_name, *, max_new: int) -> None:
 
 
 def run(
+    cfg,
     *,
     workers: int = 2,
     n_requests: int = 32,
@@ -167,8 +143,8 @@ def run(
     rollover: bool = False,
 ) -> None:
     from repro import models
-    from repro.ckpt import bundle_from_params
     from repro.core import shm_arena
+    from repro.launch.serve import publish_model
     from repro.serve import run_traffic
 
     from .common import emit, emit_value, fresh_workspace
@@ -176,7 +152,9 @@ def run(
     print("name,us_per_call,derived")
     ws = fresh_workspace()
     try:
-        cfg, app_name = _publish_serve_app(ws, ARCH)
+        # numpy weights: this process spawns the serving workers, so it
+        # must not take the chip
+        app_name = publish_model(ws, cfg, models.init_params_np(cfg, 0))
 
         rollover_at = n_requests // 3 if rollover else None
         pre_roll_segments: list[str] = []
@@ -191,20 +169,12 @@ def run(
                 for rec in shm_arena.list_segments(ws.registry)
                 if rec.get("kind") != "ring"
             )
-            params2 = {
-                n: np.asarray(v)
-                for n, v in models.init_params(cfg, 1).items()
-            }
-            bundle, payload = bundle_from_params(
-                f"weights:{cfg.name}", "v2", params2
-            )
-            with ws.management() as tx:
-                tx.publish(bundle, payload)
+            publish_model(ws, cfg, models.init_params_np(cfg, 1), version="v2")
 
         rep = run_traffic(
             ws,
             app_name,
-            arch=ARCH,
+            cfg=cfg,
             workers=workers,
             n_requests=n_requests,
             rate_hz=rate_hz,
@@ -314,8 +284,10 @@ def _check_rollover(ws, app_name, rep, *, workers, pre_roll_segments) -> None:
          f"commit->fleet-adopted wall;old_segments_gcd={len(pre_roll_segments)}")
 
 
-def run_chaos(*, smoke: bool = True) -> None:
+def run_chaos(cfg, *, smoke: bool = True) -> None:
     """``--chaos``: kill-a-worker tail + wedge->deadline->rollback wall."""
+    from repro import models
+    from repro.launch.serve import publish_model
     from repro.serve import run_traffic
 
     from .common import emit, emit_value, fresh_workspace, write_bench_json
@@ -325,7 +297,7 @@ def run_chaos(*, smoke: bool = True) -> None:
     print("name,us_per_call,derived")
     ws = fresh_workspace()
     try:
-        cfg, app_name = _publish_serve_app(ws, ARCH)
+        app_name = publish_model(ws, cfg, models.init_params_np(cfg, 0))
 
         # Half 1: SIGKILL worker 0 mid-decode under a supervised fleet.
         # The supervisor must finish the whole schedule anyway: dead-owner
@@ -337,7 +309,7 @@ def run_chaos(*, smoke: bool = True) -> None:
         rep = run_traffic(
             ws,
             app_name,
-            arch=ARCH,
+            cfg=cfg,
             workers=workers,
             n_requests=n_requests,
             rate_hz=200.0,
@@ -376,8 +348,8 @@ def _bench_rollback_wall(cfg, ws, app_name) -> None:
     """Commit v2, wedge the reload, adopt with a deadline; time the
     recovery (deadline fires -> abort_adopt -> serving v1 bytes again)."""
     from repro import models
-    from repro.ckpt import bundle_from_params
     from repro.core.errors import AdoptDeadlineError
+    from repro.launch.serve import publish_model
     from repro.serve import FaultPlan, ServeEngine
     from repro.serve import faults as serve_faults
 
@@ -387,13 +359,7 @@ def _bench_rollback_wall(cfg, ws, app_name) -> None:
     good = _image_digest(ws.load(app_name, strategy="stable-mmap-cached"))
     gen_before = ws.epoch_gen
 
-    params2 = {
-        n: np.asarray(v) for n, v in models.init_params(cfg, 7).items()
-    }
-    bundle, payload = bundle_from_params(f"weights:{cfg.name}", "v2-bad",
-                                         params2)
-    with ws.management() as tx:
-        tx.publish(bundle, payload)
+    publish_model(ws, cfg, models.init_params_np(cfg, 7), version="v2-bad")
 
     deadline_s = 0.25
     serve_faults.install(FaultPlan(wedge_adopt_s=30.0))
@@ -426,14 +392,18 @@ def _bench_rollback_wall(cfg, ws, app_name) -> None:
 
 
 def main() -> None:
+    from repro.configs import get_config
+
+    smoke = "--smoke" in sys.argv
+    cfg = get_config(ARCH, smoke=smoke)
     if "--chaos" in sys.argv:
-        run_chaos(smoke="--smoke" in sys.argv)
+        run_chaos(cfg, smoke=smoke)
         return
     rollover = "--rollover" in sys.argv
-    if "--smoke" in sys.argv:
-        run(workers=2, n_requests=24, rate_hz=200.0, rollover=rollover)
+    if smoke:
+        run(cfg, workers=2, n_requests=24, rate_hz=200.0, rollover=rollover)
         return
-    run(workers=3, n_requests=96, rate_hz=400.0, max_batch=4,
+    run(cfg, workers=3, n_requests=96, rate_hz=400.0, max_batch=4,
         rollover=rollover)
 
 

@@ -283,7 +283,7 @@ def main() -> None:
     from repro.serve import run_traffic
 
     rep = run_traffic(
-        ws, "serve:mamba", arch="mamba2-370m",
+        ws, "serve:mamba", cfg=tr_cfg,
         workers=2, n_requests=8, rate_hz=50.0,
         prompt_len=8, max_new_tokens=6, max_batch=2,
     )
@@ -337,7 +337,7 @@ def main() -> None:
 
 
     rep2 = run_traffic(
-        ws, "serve:mamba", arch="mamba2-370m",
+        ws, "serve:mamba", cfg=tr_cfg,
         workers=2, n_requests=9, rate_hz=50.0,
         prompt_len=8, max_new_tokens=6, max_batch=2,
         rollover_at=3, rollover_fn=commit_v2,
@@ -566,7 +566,7 @@ def main() -> None:
     # multiple producers reserve slots through a bakery-locked claim
     # cursor, then write and publish independently.
     rep10 = run_traffic(
-        ws, "serve:mamba", arch="mamba2-370m",
+        ws, "serve:mamba", cfg=tr_cfg,
         workers=2, n_requests=6, rate_hz=50.0,
         prompt_len=8, max_new_tokens=6, max_batch=2,
         stream=True, temperature=0.7, top_k=8, sampling_seed=42,
@@ -593,7 +593,7 @@ def main() -> None:
     # determinism across runs: same (seed, rid, position) -> same stream,
     # regardless of arrival timing or batch composition
     rep10b = run_traffic(
-        ws, "serve:mamba", arch="mamba2-370m",
+        ws, "serve:mamba", cfg=tr_cfg,
         workers=1, n_requests=6, rate_hz=200.0,
         prompt_len=8, max_new_tokens=6, max_batch=3,
         stream=True, temperature=0.7, top_k=8, sampling_seed=42,

@@ -6,9 +6,9 @@ XLA compilation. Stable linking's discipline applies verbatim: the program
 executable is materialized at end_mgmt and *loaded* at job start.
 
 Keys are content hashes over (program key, mesh key, world hash). The store
-uses ``jax.experimental.serialize_executable`` when available; environments
-where serialized executables cannot round-trip fall back to an in-memory
-cache plus recompilation (recorded in stats so benchmarks stay honest).
+uses ``jax.experimental.serialize_executable``; where a serialized
+executable cannot round-trip, the cache falls back to memory plus
+recompilation, and ``CompileStats.cache_error`` says why.
 
 jax is imported lazily — core/ stays importable without it.
 """
@@ -38,6 +38,7 @@ class CompileStats:
     lower_s: float = 0.0
     compile_s: float = 0.0
     deserialize_s: float = 0.0
+    cache_error: str = ""     # why the disk artifact was not read or written
 
 
 @dataclass
@@ -58,8 +59,9 @@ class CompileCache:
         """Return a compiled executable for ``key``.
 
         ``lower_fn`` must return a ``jax.stages.Lowered`` (called only on
-        cache miss). Serialization failures degrade gracefully to memory
-        caching.
+        cache miss). A disk artifact that fails to deserialize is compiled
+        again, and an executable that fails to serialize stays in memory;
+        either failure is recorded in ``stats.cache_error``.
         """
         stats = stats if stats is not None else CompileStats()
         stats.key = key
@@ -81,8 +83,8 @@ class CompileCache:
                 stats.source = "disk"
                 self.memory[key] = compiled
                 return compiled, stats
-            except Exception:
-                pass  # stale/incompatible artifact: recompile below
+            except Exception as e:  # stale/incompatible artifact
+                stats.cache_error = f"deserialize: {type(e).__name__}: {e}"
 
         t0 = time.perf_counter()
         lowered = lower_fn()
@@ -107,6 +109,6 @@ class CompileCache:
                 )
             )
             tmp.rename(p)
-        except Exception:
-            pass  # serialization unsupported on this backend: memory-only
+        except Exception as e:  # unsupported on this backend: memory-only
+            stats.cache_error = f"serialize: {type(e).__name__}: {e}"
         return compiled, stats

@@ -654,13 +654,14 @@ def _close_live_segments() -> None:  # pragma: no cover - interpreter exit
 
 
 # ------------------------------------------------------------------- fleet
-def _fleet_worker(root, app_name, strategy, arch, max_new, barrier, queue,
+def _fleet_worker(root, app_name, strategy, cfg, max_new, barrier, queue,
                   store_url=None):
     """Spawn-target for one fleet replica (module-level: picklable by name).
 
     Imports stay inside the function so a load-only probe never pays the
-    jax import; ``arch`` promotes the worker to a full ``ServeEngine``
-    replica that generates ``max_new`` tokens after attaching. Failures are
+    jax import; ``cfg`` (the parent's ``ModelConfig``) promotes the worker
+    to a full ``ServeEngine`` replica that generates ``max_new`` tokens
+    after attaching and reports the device it ran on. Failures are
     REPORTED, not swallowed: the worker pushes a structured error record
     (exception repr + traceback excerpt) so the parent's ``FleetReport``
     can name what died instead of timing out on silence."""
@@ -694,11 +695,10 @@ def _fleet_worker(root, app_name, strategy, arch, max_new, barrier, queue,
             "segment": image.stats.shm_segment,
             "tensors_digest": h.hexdigest(),
         }
-        if arch is not None:
-            from repro.configs import get_config
+        if cfg is not None:
+            from repro.core.chips import device_report
             from repro.serve import ServeEngine
 
-            cfg = get_config(arch, smoke=True)
             engine = ServeEngine.from_workspace(
                 cfg, ws, app_name, strategy=strategy
             )
@@ -707,6 +707,7 @@ def _fleet_worker(root, app_name, strategy, arch, max_new, barrier, queue,
             out, stats = engine.generate(prompts, max_new or 4)
             result["tokens_out"] = int(stats.tokens_out)
             result["sample"] = out[0, :4].tolist()
+            result["device"] = device_report()
         queue.put(result)
     except BaseException as e:
         import traceback as _tb
@@ -729,7 +730,7 @@ def run_fleet(
     *,
     processes: int = 2,
     strategy: str = "stable-shm",
-    arch: Optional[str] = None,
+    cfg=None,
     max_new: int = 0,
     timeout: float = 180.0,
     store_url: Optional[str] = None,
@@ -746,8 +747,12 @@ def run_fleet(
     stalling the fleet until the timeout — a crashed worker is accounted
     for the moment its process dies (SIGKILL included, in which case the
     record is synthesized from the exit code since the worker never got to
-    report its own traceback)."""
+    report its own traceback). Worker i is pinned to chip i
+    (``core.chips.pinned_to_chip``); with ``cfg`` each builds a serving
+    engine for it."""
     import multiprocessing as mp
+
+    from .chips import pinned_to_chip
 
     if processes < 1:
         raise ValueError("processes must be >= 1")
@@ -757,7 +762,7 @@ def run_fleet(
     procs = [
         ctx.Process(
             target=_fleet_worker,
-            args=(os.fspath(root), app_name, strategy, arch, max_new,
+            args=(os.fspath(root), app_name, strategy, cfg, max_new,
                   barrier, queue, store_url),
             daemon=True,
         )
@@ -766,8 +771,9 @@ def run_fleet(
     import queue as _queue
 
     deadline = time.monotonic() + timeout
-    for p in procs:
-        p.start()
+    for i, p in enumerate(procs):
+        with pinned_to_chip(i):
+            p.start()
     results: list[dict] = []
     synthesized: set[int] = set()  # pids whose death we recorded ourselves
 

@@ -20,8 +20,9 @@ Async pairs are counted once on the ``-start`` op (whose result is a tuple;
 the transferred operand is its last element); ``-done`` ops and operand
 mentions of collective instruction names never match.
 
-Hardware constants are per-chip TPU-class figures; only their ratios matter
-for dominance analysis, and tests rely on ratios alone.
+The per-chip peaks that turn those terms into seconds come from ``PEAKS``,
+keyed by the ``device_kind`` JAX reports; a kind missing from the table is
+an error, never a default.
 """
 
 from __future__ import annotations
@@ -29,9 +30,30 @@ from __future__ import annotations
 import re
 from dataclasses import dataclass, field
 
-PEAK_FLOPS = 4.59e14   # bf16 FLOP/s per chip
-HBM_BW = 2.765e12      # HBM bytes/s per chip
-ICI_BW = 9.0e10        # interconnect bytes/s per chip per direction
+
+@dataclass(frozen=True)
+class ChipPeaks:
+    flops: float       # bf16 FLOP/s
+    hbm_bw: float      # HBM bytes/s
+    ici_bw: float      # chip-to-chip interconnect bytes/s
+
+
+# Published per-chip peaks. "TPU v5 lite" is TPU v5e (Google Cloud
+# documentation, "TPU v5e"): 197 TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s
+# chip-to-chip interconnect.
+PEAKS: dict[str, ChipPeaks] = {
+    "TPU v5 lite": ChipPeaks(flops=197e12, hbm_bw=819e9, ici_bw=1600e9 / 8),
+}
+
+
+def peaks_for(device_kind: str) -> ChipPeaks:
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no published peaks for device kind {device_kind!r}; "
+            f"known: {sorted(PEAKS)}"
+        ) from None
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -135,19 +157,20 @@ class Roofline:
     flops: float
     hbm_bytes: float
     coll_bytes: float
+    peaks: ChipPeaks
     model_flops: float = 0.0  # useful (model-math) FLOPs, for MFU
 
     @property
     def compute_s(self) -> float:
-        return self.flops / PEAK_FLOPS
+        return self.flops / self.peaks.flops
 
     @property
     def memory_s(self) -> float:
-        return self.hbm_bytes / HBM_BW
+        return self.hbm_bytes / self.peaks.hbm_bw
 
     @property
     def collective_s(self) -> float:
-        return self.coll_bytes / ICI_BW
+        return self.coll_bytes / self.peaks.ici_bw
 
     @property
     def dominant(self) -> str:
@@ -171,7 +194,7 @@ class Roofline:
         """MFU upper bound: useful-compute time / roofline-bound time."""
         if not self.bound_s:
             return 0.0
-        return (self.model_flops / PEAK_FLOPS) / self.bound_s
+        return (self.model_flops / self.peaks.flops) / self.bound_s
 
     def to_json(self) -> dict:
         return {

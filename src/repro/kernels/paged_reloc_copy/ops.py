@@ -30,7 +30,7 @@ def apply_page_table(
     blob: np.ndarray,
     arena: np.ndarray,
     *,
-    impl: str = "pallas_interpret",
+    impl: str = "pallas",
 ) -> jax.Array:
     """Execute a compiled page table: impl in {pallas, pallas_interpret, ref}."""
     src = jnp.asarray(pt.src_page)

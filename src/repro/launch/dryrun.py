@@ -37,12 +37,17 @@ from repro.dist.hlo_analysis import (
     Roofline,
     collective_stats,
     cost_analysis_terms,
+    peaks_for,
 )
 from repro.dist.sharding import ShardingRules
 from repro.launch.mesh import mesh_from_spec
 from repro.launch.steps import build_step
 
 RESULTS_DIR = Path(__file__).resolve().parents[3] / "benchmarks" / "results"
+
+# The chip whose peaks turn cost terms into seconds. The devices this
+# process lowers on are fake CPU devices, so their kind says nothing.
+CHIP = "TPU v5 lite"
 
 
 def default_microbatches(shape) -> int:
@@ -98,6 +103,7 @@ def run_cell(
         "mesh": mesh_spec,
         "variant": variant,
         "kind": shape.kind,
+        "chip": CHIP,
     }
     reason = skip_reason(cfg, shape)
     if reason:
@@ -141,6 +147,7 @@ def run_cell(
             flops=flops,
             hbm_bytes=hbm,
             coll_bytes=coll.total_bytes,
+            peaks=peaks_for(CHIP),
             model_flops=model_flops_per_chip(cfg, shape, n_dev),
         )
         print(
@@ -260,6 +267,7 @@ def run_cost_probe(
         flops=float(est[0]),
         hbm_bytes=float(est[1]),
         coll_bytes=float(est[2]),
+        peaks=peaks_for(CHIP),
         model_flops=model_flops_per_chip(cfg, shape, n_dev),
     )
     return {"roofline": roof.to_json(), "probes": probe_info,

@@ -13,6 +13,7 @@ gradient all-reduce.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 # Flags a real TPU deployment sets for compute/communication overlap; the
 # CPU dry-run ignores them but records them here as part of the launch
@@ -29,15 +30,21 @@ TPU_PERF_XLA_FLAGS = " ".join(
 )
 
 
+def _mesh(shape, axes):
+    # Auto axes: models place activations with with_sharding_constraint
+    # (dist.context.constrain), which refers to Auto axes only
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_local_mesh():
     """1-device mesh with the production axis names (tests/smoke runs)."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return _mesh((1, 1), ("data", "model"))
 
 
 def mesh_from_spec(spec: str):
@@ -50,4 +57,4 @@ def mesh_from_spec(spec: str):
         return make_local_mesh()
     dims = tuple(int(x) for x in spec.split("x"))
     axes = ("pod", "data", "model")[-len(dims):]
-    return jax.make_mesh(dims, axes)
+    return _mesh(dims, axes)

@@ -16,6 +16,7 @@ import json
 import tempfile
 
 from repro.configs import ARCHS, ShapeConfig, get_config
+from repro.core.chips import compile_cache_dir
 from repro.launch.mesh import make_local_mesh, mesh_from_spec
 from repro.optim import OptConfig
 from repro.train import TrainConfig, Trainer
@@ -37,6 +38,7 @@ def main() -> None:
     ap.add_argument("--fail-at-step", type=int, default=-1)
     args = ap.parse_args()
 
+    compile_cache_dir()
     cfg = get_config(args.arch, smoke=args.smoke)
     shape = ShapeConfig("cli", args.seq, args.batch, "train")
     mesh = mesh_from_spec(args.mesh) if args.mesh != "local" else make_local_mesh()

@@ -2,6 +2,7 @@
 
     param_specs(cfg)                  -> {name: ParamSpec}   (symbol manifest)
     init_params(cfg, seed)            -> {name: array}
+    init_params_np(cfg, seed)         -> {name: np.ndarray}  (no JAX backend)
     loss_fn(cfg, params, batch)       -> scalar
     forward(cfg, params, batch)       -> (logits, aux)
     prefill(cfg, params, batch)       -> (logits, cache)
@@ -22,6 +23,7 @@ from repro.core import SymbolRef
 
 from . import hybrid, mamba2, transformer
 from .specs import ParamSpec, abstract_params, init_params as _init
+from .specs import init_params_np as _init_np
 from .specs import param_bytes, param_count
 
 
@@ -39,6 +41,10 @@ def param_specs(cfg) -> dict[str, ParamSpec]:
 
 def init_params(cfg, seed: int = 0):
     return _init(param_specs(cfg), seed)
+
+
+def init_params_np(cfg, seed: int = 0):
+    return _init_np(param_specs(cfg), seed)
 
 
 def forward(cfg, params, batch, *, impl="chunked"):

@@ -25,6 +25,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.resolver import np_dtype
+
 
 @dataclass(frozen=True)
 class ParamSpec:
@@ -37,9 +39,12 @@ class ParamSpec:
         assert len(self.axes) == len(self.shape), (self.axes, self.shape)
 
 
+def _name_hash(name: str) -> int:
+    return int.from_bytes(hashlib.blake2b(name.encode(), digest_size=4).digest(), "big")
+
+
 def _name_key(base: jax.Array, name: str) -> jax.Array:
-    h = int.from_bytes(hashlib.blake2b(name.encode(), digest_size=4).digest(), "big")
-    return jax.random.fold_in(base, h)
+    return jax.random.fold_in(base, _name_hash(name))
 
 
 def _init_one(key: jax.Array, spec: ParamSpec) -> jax.Array:
@@ -65,10 +70,33 @@ def init_params(
     return {n: _init_one(_name_key(base, n), s) for n, s in specs.items()}
 
 
+def _init_one_np(rng: np.random.Generator, spec: ParamSpec) -> np.ndarray:
+    dt = np_dtype(spec.dtype)
+    if spec.init == "zeros":
+        return np.zeros(spec.shape, dt)
+    if spec.init == "ones":
+        return np.ones(spec.shape, dt)
+    if spec.init == "fan_in":
+        fan_in = spec.shape[-2] if len(spec.shape) >= 2 else spec.shape[-1]
+        std = fan_in ** -0.5
+    else:
+        std = 0.02
+    a = rng.standard_normal(spec.shape, dtype=np.float32)
+    a *= np.float32(std)
+    return a.astype(dt)
+
+
 def init_params_np(
     specs: Mapping[str, ParamSpec], seed: int = 0
 ) -> dict[str, np.ndarray]:
-    return {n: np.asarray(v) for n, v in init_params(specs, seed).items()}
+    """``init_params`` on the host, with no JAX backend: a publisher builds
+    weights without taking the chip. Same per-name seeding (each param's
+    generator is keyed by ``(seed, name)``); numpy's generator, so the
+    values differ from ``init_params``."""
+    return {
+        n: _init_one_np(np.random.default_rng((seed, _name_hash(n))), s)
+        for n, s in specs.items()
+    }
 
 
 def abstract_params(specs: Mapping[str, ParamSpec]) -> dict[str, jax.ShapeDtypeStruct]:

@@ -293,7 +293,7 @@ class ServeEngine:
         *,
         processes: int = 2,
         strategy: str = "stable-shm",
-        arch: str | None = None,
+        cfg=None,
         max_new: int = 0,
         timeout: float = 180.0,
         store_url: str | None = None,
@@ -305,8 +305,9 @@ class ServeEngine:
         ``ws.root`` and loads ``app_name`` with ``strategy`` (default
         ``stable-shm``): the first worker on the machine publishes the
         baked arena into a named shm segment, every other replica attaches
-        to that one physical copy instead of re-mapping. With ``arch`` set,
-        each worker additionally constructs a full ``ServeEngine`` and
+        to that one physical copy instead of re-mapping. With ``cfg`` (a
+        ``ModelConfig``, handed to every worker as it is) set, each worker
+        additionally constructs a full ``ServeEngine`` on its own chip and
         greedy-decodes ``max_new`` tokens, proving end-to-end serving from
         the shared segment. Returns a ``FleetReport`` (fills/attaches per
         the one-fill-per-machine contract, per-worker load stats and
@@ -325,7 +326,7 @@ class ServeEngine:
             app_name,
             processes=processes,
             strategy=strategy,
-            arch=arch,
+            cfg=cfg,
             max_new=max_new,
             timeout=timeout,
             store_url=store_url,

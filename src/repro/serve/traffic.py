@@ -15,10 +15,12 @@ with a dead owner pid — either way the next ``ws.gc()`` reclaims the
 segment (``core.shm_arena.gc_segments``), which is the acceptance bar for
 this subsystem.
 
-Each worker loads the app through the stable-linking epoch path (default
-``stable-shm``: one physical arena copy machine-wide), builds a
-``ServeEngine``, and runs ``engine.serve_loop`` — the continuous-batching
-scheduler — with its rings as source and sink. The dispatcher drives
+Each worker is pinned to its own chip (``core.chips.pinned_to_chip``:
+worker i holds chip i), loads the app through the stable-linking epoch
+path (default ``stable-shm``: one physical arena copy machine-wide), builds
+a ``ServeEngine`` for the ``ModelConfig`` the dispatcher hands it, and
+runs ``engine.serve_loop`` — the continuous-batching scheduler — with its
+rings as source and sink. The dispatcher drives
 Poisson arrivals, round-robins requests across workers (ring-full = the
 scheduler's ``max_queue`` backpressure, surfaced as a routing decision),
 and measures what serving people actually report: sustained req/s, tok/s,
@@ -39,7 +41,9 @@ Wire format (fixed little-endian structs + int32 token payloads):
                2 PARTIAL — a streamed token span: the ``admitted`` field
                carries the span's starting seq, the payload its tokens)
     rid sentinels: -1 STOP (drain and exit), -2 worker READY (engine
-    built; payload = per-worker spin-up seconds), -3 worker ERROR
+    built; ``admitted`` = spin-up seconds, payload = JSON
+    ``core.chips.device_report``: platform, kind, count, device id and the
+    chip device nodes held), -3 worker ERROR
     (payload = utf-8 traceback excerpt, surfaced in the report instead of
     a silent join timeout), -4 worker ADOPTED (blue/green flip complete;
     payload = JSON {worker, epoch_gen, digest} where digest content-hashes
@@ -90,6 +94,7 @@ in progress (``rollover_p99_s``) from steady state.
 
 from __future__ import annotations
 
+import json
 import math
 import multiprocessing as mp
 import struct
@@ -99,6 +104,7 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
+from repro.core.chips import pinned_to_chip
 from repro.core.shm_ring import ShmRing, ShmRingError, ring_owner_alive
 
 # rid, max_new, n_toks, enqueued (NaN = no clock), deadline, priority
@@ -211,7 +217,7 @@ def ring_slot_bytes(prompt_len: int, max_new: int) -> int:
 def _traffic_worker(
     root,
     app_name: str,
-    arch: str,
+    cfg,
     strategy: str,
     session: str,
     widx: int,
@@ -228,7 +234,8 @@ def _traffic_worker(
 ) -> None:
     """One serving worker: epoch-path engine + serve_loop over the rings.
 
-    Module-level so the spawn context can pickle it. The response ring is
+    Module-level so the spawn context can pickle it; ``cfg`` is the
+    dispatcher's ``ModelConfig``, pickled along. The response ring is
     created FIRST (before the expensive engine build) so the dispatcher's
     attach never races jit compilation; READY (with the spin-up time as
     payload) is pushed only after the engine exists. Any failure is
@@ -242,7 +249,7 @@ def _traffic_worker(
     """
     import traceback as _tb
 
-    from repro.configs import get_config
+    from repro.core.chips import device_report
     from repro.link import Workspace
 
     from . import faults
@@ -257,7 +264,6 @@ def _traffic_worker(
     )
     try:
         t0 = time.monotonic()
-        cfg = get_config(arch, smoke=True)
         engine = ServeEngine.from_workspace(
             cfg, ws, app_name, strategy=strategy, cache_len=cache_len
         )
@@ -266,7 +272,10 @@ def _traffic_worker(
         )
         _push_blocking(
             rsp,
-            _encode_blob(_RID_READY, b"", time.monotonic() - t0),
+            _encode_blob(
+                _RID_READY, json.dumps(device_report()).encode(),
+                time.monotonic() - t0,
+            ),
             timeout=30.0,
         )
 
@@ -316,7 +325,6 @@ def _traffic_worker(
 
         def on_epoch(change):
             import hashlib as _hashlib
-            import json as _json
 
             image = engine.adopt_epoch(
                 ws, app_name, strategy=strategy,
@@ -330,7 +338,7 @@ def _traffic_worker(
                     .view(np.uint8)
                     .tobytes()
                 )
-            blob = _json.dumps(
+            blob = json.dumps(
                 {
                     "worker": widx,
                     "epoch_gen": change.epoch_gen,
@@ -373,6 +381,8 @@ class TrafficReport:
     wall_s: float = 0.0                 # first send -> last completion
     latencies_s: list = field(default_factory=list)
     ready_s: list = field(default_factory=list)   # per-worker spin-up
+    devices: list = field(default_factory=list)   # per-worker device report
+    outputs: dict = field(default_factory=dict)   # rid -> completed tokens
     worker_errors: list = field(default_factory=list)
     # blue/green rollover (populated when run_traffic rolled mid-load):
     rollover_at: int | None = None      # request index the roll started after
@@ -499,6 +509,7 @@ class TrafficReport:
             "p50_latency_s": round(self.p50_s, 4),
             "p99_latency_s": round(self.p99_s, 4),
             "ready_s": [round(r, 3) for r in self.ready_s],
+            "devices": self.devices,
             "rollover_at": self.rollover_at,
             "adoptions": self.adoptions,
             "rollover_wall_s": round(self.rollover_wall_s, 4),
@@ -525,7 +536,7 @@ def run_traffic(
     ws,
     app_name: str,
     *,
-    arch: str,
+    cfg,
     workers: int = 2,
     n_requests: int = 16,
     rate_hz: float = 50.0,
@@ -554,14 +565,20 @@ def run_traffic(
     """Drive a Poisson request load through a spawned serving fleet.
 
     Spawns ``workers`` real processes (spawn context — jax state never
-    forks), each serving ``engine.serve_loop`` over its ring pair, and
+    forks), worker i pinned to chip i, each building a ``ServeEngine`` for
+    ``cfg`` and serving ``engine.serve_loop`` over its ring pair, and
     sends ``n_requests`` with exponential inter-arrival times at
     ``rate_hz``. Requests round-robin across workers; a full request ring
     routes to the next worker, and a fully-backpressured fleet defers the
     send (counted in ``stalls``). Returns a ``TrafficReport`` with
     sustained req/s, tok/s, and p50/p99 end-to-end latency; worker
     crashes surface as structured ``worker_errors`` records (exit code +
-    traceback excerpt) rather than a join timeout.
+    traceback excerpt) rather than a join timeout. Each worker's READY
+    frame lands in ``report.devices`` (the device it serves on), and each
+    completed request's tokens in ``report.outputs``.
+
+    The dispatcher itself never touches a JAX backend: a parent that held
+    the chip would leave its workers none.
 
     ``warmup_per_worker`` requests are pushed to every worker and drained
     BEFORE the measured phase, so each worker's jit compilation (prefill +
@@ -616,7 +633,7 @@ def run_traffic(
     session = session or f"traffic-{uuid.uuid4().hex[:8]}"
     slot_bytes = ring_slot_bytes(prompt_len, max_new_tokens)
     report = TrafficReport(
-        workers=workers, strategy=strategy, arch=arch, rate_hz=rate_hz
+        workers=workers, strategy=strategy, arch=cfg.name, rate_hz=rate_hz
     )
 
     ctx = mp.get_context("spawn")
@@ -633,7 +650,7 @@ def run_traffic(
     ]
     def _worker_args(i: int, plan: dict | None):
         return (
-            ws.root, app_name, arch, strategy, session, i,
+            ws.root, app_name, cfg, strategy, session, i,
             cache_len, max_batch, max_new_tokens, slot_bytes,
             plan, adopt_deadline_s,
             stream, temperature, top_k, sampling_seed,
@@ -647,8 +664,9 @@ def run_traffic(
         )
         for i in range(workers)
     ]
-    for p in procs:
-        p.start()
+    for i, p in enumerate(procs):
+        with pinned_to_chip(i):
+            p.start()
     rsp_rings = [
         ShmRing.attach(ws.registry, rsp_channel(session, i), timeout=60.0)
         for i in range(workers)
@@ -656,7 +674,7 @@ def run_traffic(
 
     rng = np.random.default_rng(seed)
     prompts = rng.integers(
-        0, 32000, (n_requests, prompt_len), dtype=np.int32
+        0, cfg.vocab_size, (n_requests, prompt_len), dtype=np.int32
     )
     gaps = rng.exponential(1.0 / max(rate_hz, 1e-9), n_requests)
     alive = [True] * workers
@@ -721,7 +739,8 @@ def run_traffic(
         p = ctx.Process(
             target=_traffic_worker, args=_worker_args(i, None), daemon=True
         )
-        p.start()
+        with pinned_to_chip(i):
+            p.start()
         procs[i] = p
         rsp_rings[i] = ShmRing.attach(
             ws.registry, rsp_channel(session, i), timeout=60.0
@@ -768,11 +787,10 @@ def run_traffic(
                 rid, payload, a, f, enq, status = decode_completion(data)
                 if rid == _RID_READY:
                     report.ready_s.append(a)
+                    report.devices.append(json.loads(payload.decode()))
                 elif rid == _RID_ADOPTED:
-                    import json as _json
-
                     report.adoptions.append(
-                        _json.loads(payload.decode(errors="replace"))
+                        json.loads(payload.decode(errors="replace"))
                     )
                     if roll_active and len(report.adoptions) >= sum(alive):
                         # every surviving worker now serves generation N+1
@@ -816,6 +834,7 @@ def run_traffic(
                         spans.pop(rid, None)  # partial stream: unverifiable
                     else:
                         report.tokens_out += int(payload.size)
+                        report.outputs[rid] = payload
                         if enq is not None:
                             report.latencies_s.append(now - enq)
                             if roll_active:

@@ -160,6 +160,7 @@ class Trainer:
             "strategy": image.stats.strategy,
             "load_s": image.stats.startup_s,
             "compile_source": cstats.source,
+            "compile_cache_error": cstats.cache_error,
             "total_s": time.perf_counter() - t0,
             "resume_step": step0,
         }

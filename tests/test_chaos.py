@@ -500,7 +500,7 @@ def test_back_to_back_rollover_fleet_converges(shm_ws):
     rep = run_traffic(
         ws,
         app_name,
-        arch="mamba2-370m",
+        cfg=cfg,
         workers=2,
         n_requests=n,
         rate_hz=100.0,
@@ -538,13 +538,13 @@ def test_sigkilled_worker_respawned_zero_lost(shm_ws):
     from repro.serve import run_traffic
 
     ws = shm_ws
-    _, app_name = _publish_model(ws, "mamba2-370m")
+    cfg, app_name = _publish_model(ws, "mamba2-370m")
 
     n = 10
     rep = run_traffic(
         ws,
         app_name,
-        arch="mamba2-370m",
+        cfg=cfg,
         workers=2,
         n_requests=n,
         rate_hz=100.0,
@@ -573,13 +573,13 @@ def test_request_deadline_over_the_wire(shm_ws):
     from repro.serve import run_traffic
 
     ws = shm_ws
-    _, app_name = _publish_model(ws, "mamba2-370m")
+    cfg, app_name = _publish_model(ws, "mamba2-370m")
 
     n = 6
     rep = run_traffic(
         ws,
         app_name,
-        arch="mamba2-370m",
+        cfg=cfg,
         workers=1,
         n_requests=n,
         rate_hz=200.0,
@@ -606,13 +606,13 @@ def test_sigkilled_worker_midstream_rerouted_stream_intact(shm_ws):
     from repro.serve import run_traffic
 
     ws = shm_ws
-    _, app_name = _publish_model(ws, "mamba2-370m")
+    cfg, app_name = _publish_model(ws, "mamba2-370m")
 
     n, max_new = 10, 4
     rep = run_traffic(
         ws,
         app_name,
-        arch="mamba2-370m",
+        cfg=cfg,
         workers=2,
         n_requests=n,
         rate_hz=100.0,
@@ -649,13 +649,13 @@ def test_duplicated_stream_frames_absorbed_idempotently(shm_ws):
     from repro.serve import run_traffic
 
     ws = shm_ws
-    _, app_name = _publish_model(ws, "mamba2-370m")
+    cfg, app_name = _publish_model(ws, "mamba2-370m")
 
     n, max_new = 6, 4
     rep = run_traffic(
         ws,
         app_name,
-        arch="mamba2-370m",
+        cfg=cfg,
         workers=1,
         n_requests=n,
         rate_hz=200.0,

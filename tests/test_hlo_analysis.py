@@ -3,11 +3,10 @@
 import pytest
 
 from repro.dist.hlo_analysis import (
-    HBM_BW,
-    ICI_BW,
-    PEAK_FLOPS,
+    PEAKS,
     Roofline,
     collective_stats,
+    peaks_for,
 )
 
 HLO = """
@@ -47,11 +46,13 @@ def test_wire_byte_conventions():
 
 
 def test_roofline_terms_and_dominance():
+    v5e = peaks_for("TPU v5 lite")
     r = Roofline(
-        flops=PEAK_FLOPS,        # 1 s compute
-        hbm_bytes=HBM_BW * 2,    # 2 s memory
-        coll_bytes=ICI_BW / 2,   # 0.5 s collective
-        model_flops=PEAK_FLOPS / 2,
+        flops=v5e.flops,         # 1 s compute
+        hbm_bytes=v5e.hbm_bw * 2,  # 2 s memory
+        coll_bytes=v5e.ici_bw / 2,  # 0.5 s collective
+        peaks=v5e,
+        model_flops=v5e.flops / 2,
     )
     assert r.compute_s == pytest.approx(1.0)
     assert r.memory_s == pytest.approx(2.0)
@@ -68,3 +69,12 @@ def test_schedule_order_preserved():
         "all-gather", "all-reduce", "reduce-scatter", "collective-permute",
         "all-gather",
     ]
+
+
+def test_peaks_keyed_by_device_kind():
+    # TPU v5e, as JAX names it: 197 TFLOP/s bf16, 819 GB/s HBM
+    v5e = PEAKS["TPU v5 lite"]
+    assert (v5e.flops, v5e.hbm_bw) == (197e12, 819e9)
+    # a chip the table does not know is an error, never a default
+    with pytest.raises(KeyError, match="cpu"):
+        peaks_for("cpu")

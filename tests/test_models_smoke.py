@@ -126,3 +126,40 @@ def test_shared_block_weight_reuse_zamba():
     params2["shared_attn/wq"] = params["shared_attn/wq"] + 1.0
     pert, _ = models.forward(cfg, params2, batch, impl="naive")
     assert not np.allclose(np.asarray(base), np.asarray(pert))
+
+
+def test_init_params_np_is_host_only_and_keyed_by_name():
+    """Publishing builds weights without a JAX backend (a serving parent
+    must leave the chip to its workers); each tensor depends only on
+    (seed, name), so adding a parameter perturbs none of the others."""
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    src = Path(__file__).resolve().parents[1] / "src"
+    code = (
+        "from repro import models\n"
+        "from repro.configs import get_config\n"
+        "from repro.core.chips import jax_backend_initialized\n"
+        "models.init_params_np(get_config('mamba2-370m', smoke=True), 0)\n"
+        "assert not jax_backend_initialized()\n"
+    )
+    out = subprocess.run(
+        [sys.executable, "-c", code], capture_output=True, text=True,
+        env=dict(os.environ, PYTHONPATH=str(src)), timeout=120,
+    )
+    assert out.returncode == 0, out.stderr[-2000:]
+
+    from repro.models.specs import ParamSpec, init_params_np
+
+    specs = {"a": ParamSpec((4, 8), "bfloat16", (None, None), "fan_in"),
+             "b": ParamSpec((8,), "float32", (None,))}
+    one = init_params_np(specs, seed=5)
+    more = init_params_np(
+        dict(specs, c=ParamSpec((2,), "float32", (None,))), seed=5
+    )
+    for n in specs:
+        assert one[n].dtype == more[n].dtype
+        np.testing.assert_array_equal(one[n], more[n])
+    assert not np.array_equal(init_params_np(specs, seed=6)["b"], one["b"])

@@ -1,0 +1,124 @@
+"""Compile the serving path and the Pallas kernels for a TPU v5e chip.
+
+Nothing runs: the TPU compiler, which is installed with JAX, compiles for a
+chip that is described (``v5e:2x2``) and not attached. That catches what the
+CPU and interpret mode cannot: a program that does not fit the chip's
+memory, and a kernel the chip's compiler refuses.
+
+The topology is described in a module-scoped fixture, never at import: only
+one process at a time may load the TPU library, and every test worker
+imports this file. Keep every such compile in this one file, so that one
+worker loads the library.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro import models
+from repro.configs import get_config
+
+V5E_HBM_BYTES = 16 * 2**30
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")
+        from jax.experimental import topologies
+
+        try:
+            topo = topologies.get_topology_desc(
+                platform="tpu", topology_name="v5e:2x2"
+            )
+        except Exception as e:  # no TPU compiler in this installation
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        yield SingleDeviceSharding(topo.devices[0])
+
+
+def _on(sharding, tree):
+    return jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sharding),
+        tree,
+    )
+
+
+def _fits(compiled) -> None:
+    mem = compiled.memory_analysis()
+    used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert used < V5E_HBM_BYTES, used
+
+
+@pytest.fixture(scope="module")
+def engine():
+    from repro.serve import ServeEngine
+
+    cfg = get_config("mamba2-370m")
+    return ServeEngine(cfg, params=None, cache_len=512 + 16)
+
+
+def test_mamba2_prefill_compiles_for_v5e(one_chip, engine):
+    cfg = engine.cfg
+    params = _on(one_chip, models.abstract(cfg))
+    batch = _on(one_chip, {"tokens": jax.ShapeDtypeStruct((1, 512), jnp.int32)})
+    compiled = engine._prefill.lower(params, batch).compile()
+    _fits(compiled)
+
+
+def test_mamba2_slot_decode_step_compiles_for_v5e(one_chip, engine):
+    """The served decode program: SlotScheduler's vmapped step over 4
+    slots, each a B=1 cache row."""
+    from repro.serve.scheduler import SlotScheduler
+
+    cfg, slots = engine.cfg, 4
+    sched = SlotScheduler(engine, max_batch=slots)
+    row, _ = models.cache_spec(cfg, 1, engine.cache_len)
+    cache = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct((slots,) + s.shape, s.dtype), row
+    )
+    key = jax.random.PRNGKey(0)
+    args = _on(one_chip, (
+        models.abstract(cfg),
+        cache,
+        jax.ShapeDtypeStruct((slots, 1, 1), jnp.int32),
+        jax.ShapeDtypeStruct((slots, 16), jnp.int32),
+        jax.ShapeDtypeStruct((slots,), jnp.int32),
+        jax.ShapeDtypeStruct((slots,) + key.shape, key.dtype),
+        jax.ShapeDtypeStruct((slots,), jnp.bool_),
+    ))
+    compiled = sched._step_fn.lower(*args).compile()
+    _fits(compiled)
+
+
+def _kernel_cases():
+    from repro.kernels.flash_attention import flash_attention_bhsd
+    from repro.kernels.paged_reloc_copy import paged_reloc_copy
+    from repro.kernels.rmsnorm import rmsnorm_2d
+
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    sds = jax.ShapeDtypeStruct
+    return {
+        # starcoder2-3b attention: 24 query heads over 2 KV heads
+        "flash_attention": (flash_attention_bhsd, (
+            sds((1, 24, 2048, 128), bf16),
+            sds((1, 2, 2048, 128), bf16),
+            sds((1, 2, 2048, 128), bf16),
+        )),
+        "rmsnorm": (rmsnorm_2d, (sds((4096, 1024), bf16), sds((1024,), bf16))),
+        "paged_reloc_copy": (paged_reloc_copy, (
+            sds((4096, 8, 128), i32),
+            sds((4096, 8, 128), i32),
+            sds((4096,), i32),
+            sds((4096,), i32),
+        )),
+    }
+
+
+@pytest.mark.parametrize("kernel", ["flash_attention", "rmsnorm", "paged_reloc_copy"])
+def test_kernel_lowers_to_tpu_custom_call(one_chip, kernel):
+    fn, shapes = _kernel_cases()[kernel]
+    compiled = fn.lower(*_on(one_chip, shapes)).compile()
+    assert "tpu_custom_call" in compiled.as_text()

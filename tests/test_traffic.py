@@ -525,11 +525,11 @@ def test_run_traffic_end_to_end(shm_ws):
     from repro.serve import run_traffic
 
     ws = shm_ws
-    _, app_name = _publish_model(ws, "mamba2-370m")
+    cfg, app_name = _publish_model(ws, "mamba2-370m")
     rep = run_traffic(
         ws,
         app_name,
-        arch="mamba2-370m",
+        cfg=cfg,
         workers=2,
         n_requests=8,
         rate_hz=200.0,
@@ -593,7 +593,7 @@ def test_rollover_under_live_traffic(shm_ws):
     rep = run_traffic(
         ws,
         app_name,
-        arch="mamba2-370m",
+        cfg=cfg,
         workers=2,
         n_requests=n,
         rate_hz=100.0,
@@ -1165,12 +1165,12 @@ def test_run_traffic_streaming_end_to_end(shm_ws):
     from repro.serve import run_traffic
 
     ws = shm_ws
-    _, app_name = _publish_model(ws, "mamba2-370m")
+    cfg, app_name = _publish_model(ws, "mamba2-370m")
     n, max_new = 8, 4
     rep = run_traffic(
         ws,
         app_name,
-        arch="mamba2-370m",
+        cfg=cfg,
         workers=2,
         n_requests=n,
         rate_hz=200.0,
@@ -1203,3 +1203,67 @@ def test_run_traffic_streaming_end_to_end(shm_ws):
         shm_arena.shm_records_dir(ws.registry).glob("repro-ring-*.json")
     )
     assert recs == []
+
+
+# ------------------------------------------- config handed down by the parent
+def _parent_only_config():
+    """A config the registry cannot rebuild from its name: a worker that
+    served anything else would fail to load it or decode other tokens."""
+    from repro.configs import get_config
+
+    return get_config("mamba2-370m", smoke=True).replace(
+        name="mamba2-parent-only", num_layers=3
+    )
+
+
+def _publish_cfg(ws, cfg):
+    from repro import models
+    from repro.launch.serve import publish_model
+
+    return publish_model(ws, cfg, models.init_params_np(cfg, 0))
+
+
+def test_traffic_worker_serves_the_parents_config(shm_ws):
+    from repro.serve import ServeEngine, run_traffic
+
+    ws, cfg = shm_ws, _parent_only_config()
+    app_name = _publish_cfg(ws, cfg)
+    n, prompt_len, max_new = 4, 10, 4
+    rep = run_traffic(
+        ws, app_name, cfg=cfg, workers=1, n_requests=n, rate_hz=200.0,
+        prompt_len=prompt_len, max_new_tokens=max_new, max_batch=2,
+        timeout=JOIN_S * 2,
+    )
+    assert rep.completed == n and rep.failed == 0, rep.summary()
+    assert rep.arch == cfg.name
+    assert [d["platform"] for d in rep.devices] == ["cpu"]
+    # the same prompts run_traffic draws, decoded here with the same config
+    prompts = np.random.default_rng(0).integers(
+        0, cfg.vocab_size, (n, prompt_len), dtype=np.int32
+    )
+    engine = ServeEngine.from_workspace(
+        cfg, ws, app_name, cache_len=prompt_len + max_new
+    )
+    want, _ = engine.generate(prompts, max_new)
+    for rid in range(n):
+        np.testing.assert_array_equal(rep.outputs[rid], want[rid])
+
+
+def test_fleet_worker_serves_the_parents_config(shm_ws):
+    from repro.serve import ServeEngine
+
+    ws, cfg = shm_ws, _parent_only_config()
+    app_name = _publish_cfg(ws, cfg)
+    report = ServeEngine.spawn_fleet(
+        ws, app_name, processes=1, cfg=cfg, max_new=4, timeout=JOIN_S
+    )
+    assert report.failed == 0, report.summary()
+    (worker,) = report.workers
+    assert worker["device"]["platform"] == "cpu"
+    # the fleet worker's own prompts, decoded here with the same config
+    prompts = np.random.default_rng(0).integers(
+        0, cfg.vocab_size, (2, 8), dtype=np.int32
+    )
+    engine = ServeEngine.from_workspace(cfg, ws, app_name)
+    want, _ = engine.generate(prompts, 4)
+    assert worker["sample"] == want[0, :4].tolist()
