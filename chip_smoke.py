@@ -48,6 +48,7 @@ import numpy as np  # noqa: E402
 
 from repro import models  # noqa: E402
 from repro.configs import get_config  # noqa: E402
+from repro.core import spans  # noqa: E402
 from repro.core.chips import (  # noqa: E402
     compile_cache_dir,
     device_report,
@@ -120,23 +121,13 @@ def cache_vs_forward(cfg, engine, seed: int) -> float:
     return float(np.max(np.abs(got - want)) / np.max(np.abs(want)))
 
 
-class CompileCounter:
-    """Programs lowered and backend compiles, from ``jax.monitoring``.
-
-    A lowering happens for every new program, whether or not the
-    persistent compilation cache then supplies the executable."""
-
-    def __init__(self):
-        self.lowered = 0
-        self.compiled = 0
-        self.compile_s = 0.0
-
-    def __call__(self, event: str, duration: float, **kw) -> None:
-        if event == "/jax/core/compile/jaxpr_to_mlir_module_duration":
-            self.lowered += 1
-        elif event == "/jax/core/compile/backend_compile_duration":
-            self.compiled += 1
-            self.compile_s += duration
+def compiles_since(kind: str, t_ns: int) -> list:
+    """The program's compile records of one kind since ``t_ns``
+    (``time.monotonic_ns()``): ``lower`` for every new program, whether or
+    not the persistent compilation cache then supplies the executable;
+    ``compile`` only where it did not."""
+    return [e for e in spans.compile_events()
+            if e.kind == kind and e.t1_ns >= t_ns]
 
 
 def requests(cfg, seed: int, rid0: int):
@@ -176,8 +167,6 @@ def serve_phase(cfg, seed: int) -> list[str]:
 
     from repro.serve import STOP, ServeEngine
 
-    counter = CompileCounter()
-    jax.monitoring.register_event_duration_secs_listener(counter)
     failures: list[str] = []
     ws = Workspace.ephemeral("chip-smoke-")
     try:
@@ -236,11 +225,11 @@ def serve_phase(cfg, seed: int) -> list[str]:
 
         def on_epoch(change):
             t = time.perf_counter()
-            lowered0 = counter.lowered
+            flip_ns = time.monotonic_ns()
             engine.adopt_epoch(ws, app, strategy="stable")
             jax.block_until_ready(engine.params)
             marks["flip_s"] = time.perf_counter() - t
-            marks["lowered_at_flip"] = lowered0
+            marks["flip_ns"] = flip_ns
             state["serving"] = 2
             marks["gen2_t0"] = time.perf_counter()
             pending.extend(gen2)
@@ -248,7 +237,7 @@ def serve_phase(cfg, seed: int) -> list[str]:
         def on_delta(d):
             marks.setdefault("first_token", time.perf_counter())
 
-        compiled0, compile_s0 = counter.compiled, counter.compile_s
+        loop_ns = time.monotonic_ns()
         t0 = time.perf_counter()
         engine.serve_loop(
             source, lambda c: outputs.__setitem__(c.rid, c.tokens),
@@ -266,15 +255,16 @@ def serve_phase(cfg, seed: int) -> list[str]:
                     "(a) device params differ from the published bundle "
                     "after the flip"
                 )
-            recompiled = counter.lowered - marks["lowered_at_flip"]
+            recompiled = len(compiles_since("lower", marks["flip_ns"]))
             if recompiled:
                 failures.append(
                     f"(d) the flip and the serving after it lowered "
                     f"{recompiled} programs"
                 )
+            compiled = compiles_since("compile", loop_ns)
             observe(
-                compile_s=counter.compile_s - compile_s0,
-                programs_compiled=counter.compiled - compiled0,
+                compile_s=sum(e.t1_ns - e.t0_ns for e in compiled) / 1e9,
+                programs_compiled=len(compiled),
                 first_token_s=marks["first_token"] - t0,
                 gen1_tok_per_s=N_REQUESTS * MAX_NEW / (marks["gen1_done"] - t0),
                 commit_s=marks["commit_s"],

@@ -35,6 +35,8 @@ Engine-room surface:
                                    per-slot generation counters, record-
                                    driven gc like the arenas)
     inspector, interpose         — observability + fine-grained rebinding
+    spans                        — program spans and compile records, on
+                                   the device trace's clock
     CompileCache                 — AOT executable materialization
 """
 

@@ -38,6 +38,7 @@ from contextlib import contextmanager
 from dataclasses import dataclass, field
 from typing import Iterator, Optional
 
+from repro.core import spans
 from repro.core.compile_cache import CompileCache
 from repro.core.epoch_cache import EpochCache
 from repro.core.executor import Executor, Initializer, LoadStats, _zeros_init
@@ -487,11 +488,12 @@ class Workspace:
         world: Optional[World] = None,
     ):
         """Load an application image; dispatches via the strategy registry."""
-        image = self.executor.load(name, strategy=strategy, world=world)
-        stats = getattr(image, "stats", None)
-        if stats is not None:
-            self._last_stats[name] = stats
-        return image
+        with spans.span("link.load"):
+            image = self.executor.load(name, strategy=strategy, world=world)
+            stats = getattr(image, "stats", None)
+            if stats is not None:
+                self._last_stats[name] = stats
+            return image
 
     def warmup(
         self,

@@ -28,6 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import models
+from repro.core import spans
 from repro.core.errors import AdoptDeadlineError
 
 from . import faults
@@ -136,6 +137,9 @@ class ServeEngine:
         # set by from_workspace: the LoadStats of the epoch load that
         # produced self.params (None for hand-built params)
         self.load_stats = None
+        # the slot scheduler's jitted programs, by sampling setting
+        # (``scheduler.SlotScheduler``): every serve loop shares them
+        self.slot_programs: dict = {}
 
     @classmethod
     def from_workspace(
@@ -171,11 +175,16 @@ class ServeEngine:
 
     @staticmethod
     def _lift_params(image, param_builder=None):
-        if param_builder is not None:
-            return param_builder(image)
-        if hasattr(image, "tensors"):
-            return {n: jnp.asarray(a) for n, a in image.tensors.items()}
-        return {n: jnp.asarray(image[n]) for n in image.keys()}
+        """The image's tensors as device arrays; returns once every byte
+        is on the device, so that the ``serve.lift`` span ends there."""
+        with spans.span("serve.lift"):
+            if param_builder is not None:
+                params = param_builder(image)
+            elif hasattr(image, "tensors"):
+                params = {n: jnp.asarray(a) for n, a in image.tensors.items()}
+            else:
+                params = {n: jnp.asarray(image[n]) for n in image.keys()}
+            return jax.block_until_ready(params)
 
     def _reload(self, ws, app_name, strategy, param_builder):
         """The wedgeable half of an epoch reload: load + lift (the caller
@@ -222,6 +231,11 @@ class ServeEngine:
         thread only ever touches its local ``(image, params)`` pair, which
         is discarded.
         """
+        with spans.span("serve.adopt"):
+            return self._adopt(ws, app_name, strategy, param_builder,
+                               deadline_s)
+
+    def _adopt(self, ws, app_name, strategy, param_builder, deadline_s):
         ws.refresh()
         if deadline_s and deadline_s > 0:
             box: dict = {}

@@ -33,6 +33,7 @@ loop drains queue + in-flight slots after the source signals STOP.
 
 from __future__ import annotations
 
+import threading
 import time
 from dataclasses import dataclass, field, replace
 
@@ -41,6 +42,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import models
+from repro.core import spans
 from repro.core.errors import EpochAdoptError
 
 from . import faults
@@ -134,6 +136,10 @@ class ServeLoopReport:
     admitted_by_priority: dict = field(default_factory=dict)  # class -> count
     priority_aged: int = 0           # admissions that out-ranked a higher class
     deltas_out: int = 0              # streamed TokenDelta frames emitted
+    # programs lowered on the loop's thread while it ran, by the innermost
+    # span open at the time ("serve_loop" where none was): what recompiled
+    # while serving
+    programs_lowered: dict = field(default_factory=dict)
 
     def summary(self) -> dict:
         return {
@@ -153,6 +159,7 @@ class ServeLoopReport:
             "admitted_by_priority": dict(self.admitted_by_priority),
             "priority_aged": self.priority_aged,
             "deltas_out": self.deltas_out,
+            "programs_lowered": dict(self.programs_lowered),
         }
 
 
@@ -164,6 +171,70 @@ class _Slot:
     admitted_ts: float
     steps_done: int                  # tokens already in out_buf for this slot
     first_token: int = -1            # prefill's token, host-side iff streaming
+
+
+def _programs(cfg, temp: float, top_k_n: int):
+    """The slot scheduler's sampler and its two jitted programs: ``_step``
+    (vmap-advance every slot one token) and ``_admit`` (splice one B=1
+    cache row in)."""
+
+    def _pick(logits, key, pos):
+        # greedy vs sampled is a Python-static branch: temperature is
+        # a constructor constant baked into the compiled program
+        if temp <= 0.0:
+            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        lg = logits / temp
+        if top_k_n > 0:
+            kth = jax.lax.top_k(lg, top_k_n)[0][..., -1]
+            lg = jnp.where(lg < kth, -jnp.inf, lg)
+        return jax.random.categorical(
+            jax.random.fold_in(key, pos), lg
+        ).astype(jnp.int32)
+
+    def _step(params, cache, toks, out_buf, steps, keys, active):
+        def one(c, t, k, s):
+            logits, c = models.decode_step(cfg, params, c, t)
+            return _pick(logits[0, -1], k, s), c
+
+        nxt, cache = jax.vmap(one)(cache, toks, keys, steps)
+        nxt = jnp.where(active, nxt, 0)
+        row = jnp.arange(out_buf.shape[0])
+        idx = jnp.clip(steps, 0, out_buf.shape[1] - 1)
+        out_buf = out_buf.at[row, idx].set(
+            jnp.where(active, nxt, out_buf[row, idx])
+        )
+        steps = steps + active.astype(jnp.int32)
+        return cache, nxt[:, None, None], out_buf, steps, keys
+
+    def _admit(cache, toks, out_buf, steps, keys, row_cache, tok0,
+               row_key, idx):
+        cache = jax.tree_util.tree_map(
+            lambda s, r: jax.lax.dynamic_update_slice_in_dim(
+                s, r[None].astype(s.dtype), idx, 0
+            ),
+            cache,
+            row_cache,
+        )
+        zrow = jnp.zeros((1, out_buf.shape[1]), jnp.int32)
+        zrow = zrow.at[0, 0].set(tok0)
+        out_buf = jax.lax.dynamic_update_slice_in_dim(out_buf, zrow, idx, 0)
+        steps = jax.lax.dynamic_update_slice_in_dim(
+            steps, jnp.ones((1,), jnp.int32), idx, 0
+        )
+        toks = jax.lax.dynamic_update_slice(
+            toks, tok0.reshape(1, 1, 1).astype(jnp.int32), (idx, 0, 0)
+        )
+        keys = jax.lax.dynamic_update_slice_in_dim(
+            keys, row_key[None].astype(keys.dtype), idx, 0
+        )
+        return cache, toks, out_buf, steps, keys
+
+    # donate the stacked state: both programs are in-place row updates
+    return (
+        _pick,
+        jax.jit(_step, donate_argnums=(1, 2, 3, 4, 5)),
+        jax.jit(_admit, donate_argnums=(0, 1, 2, 3, 4)),
+    )
 
 
 class SlotScheduler:
@@ -211,65 +282,12 @@ class SlotScheduler:
         self.active = np.zeros(max_batch, dtype=bool)
         self.slot_meta: list[_Slot | None] = [None] * max_batch
 
-        cfg, params = engine.cfg, engine.params
-        temp, top_k_n = self.temperature, self.top_k
-
-        def _pick(logits, key, pos):
-            # greedy vs sampled is a Python-static branch: temperature is
-            # a constructor constant baked into the compiled program
-            if temp <= 0.0:
-                return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            lg = logits / temp
-            if top_k_n > 0:
-                kth = jax.lax.top_k(lg, top_k_n)[0][..., -1]
-                lg = jnp.where(lg < kth, -jnp.inf, lg)
-            return jax.random.categorical(
-                jax.random.fold_in(key, pos), lg
-            ).astype(jnp.int32)
-
-        self._pick = _pick
-
-        def _step(params, cache, toks, out_buf, steps, keys, active):
-            def one(c, t, k, s):
-                logits, c = models.decode_step(cfg, params, c, t)
-                return _pick(logits[0, -1], k, s), c
-
-            nxt, cache = jax.vmap(one)(cache, toks, keys, steps)
-            nxt = jnp.where(active, nxt, 0)
-            row = jnp.arange(out_buf.shape[0])
-            idx = jnp.clip(steps, 0, out_buf.shape[1] - 1)
-            out_buf = out_buf.at[row, idx].set(
-                jnp.where(active, nxt, out_buf[row, idx])
-            )
-            steps = steps + active.astype(jnp.int32)
-            return cache, nxt[:, None, None], out_buf, steps, keys
-
-        def _admit(cache, toks, out_buf, steps, keys, row_cache, tok0,
-                   row_key, idx):
-            cache = jax.tree_util.tree_map(
-                lambda s, r: jax.lax.dynamic_update_slice_in_dim(
-                    s, r[None].astype(s.dtype), idx, 0
-                ),
-                cache,
-                row_cache,
-            )
-            zrow = jnp.zeros((1, out_buf.shape[1]), jnp.int32)
-            zrow = zrow.at[0, 0].set(tok0)
-            out_buf = jax.lax.dynamic_update_slice_in_dim(out_buf, zrow, idx, 0)
-            steps = jax.lax.dynamic_update_slice_in_dim(
-                steps, jnp.ones((1,), jnp.int32), idx, 0
-            )
-            toks = jax.lax.dynamic_update_slice(
-                toks, tok0.reshape(1, 1, 1).astype(jnp.int32), (idx, 0, 0)
-            )
-            keys = jax.lax.dynamic_update_slice_in_dim(
-                keys, row_key[None].astype(keys.dtype), idx, 0
-            )
-            return cache, toks, out_buf, steps, keys
-
-        # donate the stacked state: both programs are in-place row updates
-        self._step_fn = jax.jit(_step, donate_argnums=(1, 2, 3, 4, 5))
-        self._admit_fn = jax.jit(_admit, donate_argnums=(0, 1, 2, 3, 4))
+        # one set of programs per engine and sampling setting: a later loop
+        # on the same engine reuses them instead of lowering them again
+        key = (self.temperature, self.top_k)
+        if key not in engine.slot_programs:
+            engine.slot_programs[key] = _programs(engine.cfg, *key)
+        self._pick, self._step_fn, self._admit_fn = engine.slot_programs[key]
 
     def _request_key(self, rid: int):
         """The per-request PRNG key: fold the 64-bit rid into the base in
@@ -313,39 +331,44 @@ class SlotScheduler:
             raise RuntimeError("admit called with no free slot")
         idx = free[0]
         eng = self.engine
-        batch = {"tokens": jnp.asarray(req.prompt, jnp.int32)[None]}
-        if eng.cfg.is_encdec:
-            rng = np.random.default_rng(0)
-            batch["frames"] = jnp.asarray(
-                rng.standard_normal(
-                    (1, req.prompt.shape[0], eng.cfg.d_model)
-                ),
-                jnp.dtype(eng.cfg.dtype),
+        with spans.span("serve.admit.prefill"):
+            batch = {"tokens": jnp.asarray(req.prompt, jnp.int32)[None]}
+            if eng.cfg.is_encdec:
+                rng = np.random.default_rng(0)
+                batch["frames"] = jnp.asarray(
+                    rng.standard_normal(
+                        (1, req.prompt.shape[0], eng.cfg.d_model)
+                    ),
+                    jnp.dtype(eng.cfg.dtype),
+                )
+            logits, row_cache = eng._prefill(eng.params, batch)
+        with spans.span("serve.admit.sample"):
+            row_key = self._request_key(req.rid)
+            tok0 = self._pick(logits[0, -1], row_key, 0)
+        with spans.span("serve.admit.splice"):
+            if self._state is None:
+                self._init_state(
+                    row_cache, self.max_new_cap or max(req.max_new_tokens, 8)
+                )
+            if req.max_new_tokens > self.max_new_cap:
+                raise ValueError(
+                    f"request {req.rid} wants {req.max_new_tokens} tokens "
+                    f"but this loop's out_buf holds {self.max_new_cap}; "
+                    "admit the longest request first or pass max_new_cap "
+                    "to serve_loop"
+                )
+            cache, toks, out_buf, steps, keys = self._state
+            self._state = self._admit_fn(
+                cache, toks, out_buf, steps, keys, row_cache, tok0, row_key,
+                jnp.int32(idx),
             )
-        logits, row_cache = eng._prefill(eng.params, batch)
-        row_key = self._request_key(req.rid)
-        tok0 = self._pick(logits[0, -1], row_key, 0)
-        if self._state is None:
-            self._init_state(
-                row_cache, self.max_new_cap or max(req.max_new_tokens, 8)
-            )
-        if req.max_new_tokens > self.max_new_cap:
-            raise ValueError(
-                f"request {req.rid} wants {req.max_new_tokens} tokens but "
-                f"this loop's out_buf holds {self.max_new_cap}; admit the "
-                "longest request first or pass max_new_cap to serve_loop"
-            )
-        cache, toks, out_buf, steps, keys = self._state
-        self._state = self._admit_fn(
-            cache, toks, out_buf, steps, keys, row_cache, tok0, row_key,
-            jnp.int32(idx),
-        )
         self.active[idx] = True
         meta = _Slot(request=req, admitted_ts=now, steps_done=1)
         if self.stream:
             # streaming pays one extra scalar sync per ADMIT (not per
             # step) so the prefill token can ride the first PARTIAL frame
-            meta.first_token = int(tok0)
+            with spans.span("serve.admit.sync"):
+                meta.first_token = int(tok0)
         self.slot_meta[idx] = meta
         return idx
 
@@ -357,14 +380,16 @@ class SlotScheduler:
         inherently pays), else None (no sync; tokens stay device-side
         until ``pop_finished``)."""
         cache, toks, out_buf, steps, keys = self._state
-        cache, toks, out_buf, steps, keys = self._step_fn(
-            self.engine.params, cache, toks, out_buf, steps, keys,
-            jnp.asarray(self.active),
-        )
+        with spans.span("serve.step.dispatch"):
+            cache, toks, out_buf, steps, keys = self._step_fn(
+                self.engine.params, cache, toks, out_buf, steps, keys,
+                jnp.asarray(self.active),
+            )
         self._state = (cache, toks, out_buf, steps, keys)
         deltas: list[TokenDelta] | None = None
         if self.stream:
-            feed = np.asarray(toks)          # (slots, 1, 1): just-sampled
+            with spans.span("serve.step.sync"):
+                feed = np.asarray(toks)      # (slots, 1, 1): just-sampled
             deltas = [
                 TokenDelta(
                     rid=meta.request.rid,
@@ -528,6 +553,7 @@ def run_serve_loop(
     next_watch = 0.0
     stall_t0 = 0.0
     t0 = time.monotonic()
+    t0_ns = time.monotonic_ns()
 
     def _pick_next(now: float) -> Request:
         """Priority-then-FIFO with aging: highest effective class wins,
@@ -581,21 +607,24 @@ def run_serve_loop(
 
         # 1) accept traffic while there is queue room (rollover included:
         # requests queue up during the drain instead of being dropped)
-        while not draining and len(queue) < max_queue:
-            got = source()
-            if got is None:
-                break
-            if got is STOP:
-                draining = True
-                break
-            now = time.monotonic()
-            if got.deadline_s > 0 and got.enqueued_ts is None:
-                # local source with no dispatcher clock (None, NOT a zero
-                # reading — 0.0 is a representable monotonic stamp): the
-                # deadline counts from acceptance, or it could never fire
-                got = replace(got, enqueued_ts=now)
-            queue.append((got, arrivals, now))
-            arrivals += 1
+        if not draining and len(queue) < max_queue:
+            with spans.span("serve.accept"):
+                while not draining and len(queue) < max_queue:
+                    got = source()
+                    if got is None:
+                        break
+                    if got is STOP:
+                        draining = True
+                        break
+                    now = time.monotonic()
+                    if got.deadline_s > 0 and got.enqueued_ts is None:
+                        # local source with no dispatcher clock (None, NOT
+                        # a zero reading — 0.0 is a representable
+                        # monotonic stamp): the deadline counts from
+                        # acceptance, or it could never fire
+                        got = replace(got, enqueued_ts=now)
+                    queue.append((got, arrivals, now))
+                    arrivals += 1
         report.peak_queue = max(report.peak_queue, len(queue))
 
         # 1b) deadline sweep — queued requests first (they expire without
@@ -631,32 +660,37 @@ def run_serve_loop(
         now = time.monotonic()
         while pending_epoch is None and queue and sched.free_slots:
             req = _pick_next(now)
-            idx = sched.admit(req, now)
-            report.admitted += 1
-            if on_delta is not None:
-                meta = sched.slot_meta[idx]
-                on_delta(
-                    TokenDelta(rid=req.rid, seq=0,
-                               tokens=(meta.first_token,))
-                )
-                report.deltas_out += 1
+            with spans.span("serve.admit", rid=req.rid):
+                idx = sched.admit(req, now)
+                report.admitted += 1
+                if on_delta is not None:
+                    meta = sched.slot_meta[idx]
+                    on_delta(
+                        TokenDelta(rid=req.rid, seq=0,
+                                   tokens=(meta.first_token,))
+                    )
+                    report.deltas_out += 1
         report.peak_active = max(report.peak_active, sched.n_active)
 
         # 3) advance every active slot one token
-        if sched.n_active:
-            faults.on_decode_step(report.steps + 1)
-            deltas = sched.step()
-            report.steps += 1
-            if on_delta is not None and deltas:
-                for d in deltas:
-                    on_delta(d)
-                report.deltas_out += len(deltas)
+        n_active = sched.n_active
+        if n_active:
+            with spans.span("serve.step", n_active=n_active):
+                faults.on_decode_step(report.steps + 1)
+                deltas = sched.step()
+                report.steps += 1
+                if on_delta is not None and deltas:
+                    with spans.span("serve.step.emit"):
+                        for d in deltas:
+                            on_delta(d)
+                    report.deltas_out += len(deltas)
 
-            # 4) retire finished requests (one host sync each)
-            for comp in sched.pop_finished(time.monotonic()):
-                report.completed += 1
-                report.tokens_out += comp.tokens.shape[0]
-                sink(comp)
+                # 4) retire finished requests (one host sync each)
+                with spans.span("serve.step.retire"):
+                    for comp in sched.pop_finished(time.monotonic()):
+                        report.completed += 1
+                        report.tokens_out += comp.tokens.shape[0]
+                        sink(comp)
         elif queue:
             continue
         elif draining:
@@ -665,4 +699,17 @@ def run_serve_loop(
             time.sleep(idle_sleep_s)
 
     report.wall_s = time.monotonic() - t0
+    report.programs_lowered = _lowered_since(t0_ns)
     return report
+
+
+def _lowered_since(t0_ns: int) -> dict:
+    """Programs this thread lowered since ``t0_ns``, by the innermost span
+    open at the time."""
+    me = threading.get_ident()
+    out: dict[str, int] = {}
+    for e in spans.compile_events():
+        if e.kind == "lower" and e.thread == me and e.t1_ns >= t0_ns:
+            name = e.span or "serve_loop"
+            out[name] = out.get(name, 0) + 1
+    return out
