@@ -193,9 +193,10 @@ def decode_attention(
     q: jax.Array,          # (B, 1, H, hd)
     k_cache: jax.Array,    # (B, S, KV, hd)
     v_cache: jax.Array,
-    pos: jax.Array,        # scalar int32: index of the *current* token
+    pos: jax.Array,        # int32, () or (B,): index of the *current* token
 ) -> jax.Array:
-    """Single-token attention against a cache; entries beyond pos masked.
+    """Single-token attention against a cache; entries beyond pos masked
+    (``pos`` one for all rows, or one per row).
 
     GQA is computed as a grouped einsum against the UNEXPANDED cache —
     ``repeat_kv`` here would materialize (and, under SPMD, all-gather +
@@ -215,7 +216,7 @@ def decode_attention(
         "bqkgd,bskd->bkgqs", qg, k_cache,
         preferred_element_type=jnp.float32,
     ) * hd**-0.5                                         # (B,KV,G,1,S) f32
-    valid = jnp.arange(s)[None, None, None, None, :] <= pos
+    valid = jnp.arange(s) <= jnp.reshape(pos, (-1, 1, 1, 1, 1))
     scores = jnp.where(valid, scores, NEG_INF)
     w = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum(

@@ -202,16 +202,23 @@ def prefill(cfg, params, batch, *, impl: str = "chunked", cache_len=None):
 
 
 def decode_step(cfg, params, cache, tokens):
+    """tokens (B,1) + cache -> (logits (B,1,V), cache'). ``pos`` is a
+    scalar or one position per row (B,). Every cache is written in place:
+    each attention invocation its new token's K and V at each row's
+    ``pos % S``, each mamba layer its conv state, and the SSM states all
+    at once after the last layer (``mamba2.decode_layers``)."""
     B = tokens.shape[0]
     hd = _hd(cfg)
     pos = cache["pos"] + 1
+    rpos = jnp.broadcast_to(pos, (B,))
+    rows = jnp.arange(B)
     S = cache["k"].shape[2]
     x = jnp.take(params["embed/tokens"], tokens, axis=0)
     x0 = x
-    sin, cos = rope_angles(pos[None].astype(jnp.int32), hd, cfg.rope_theta)
+    sin, cos = rope_angles(rpos[:, None], hd, cfg.rope_theta)
     g = cfg.attn_every
-    stacked = mamba2._stacked(params)
-    ks, vs, convs, ssms = [], [], [], []
+    k_all, v_all, conv = cache["k"], cache["v"], cache["conv"]
+    steps = []
     for i, lo in enumerate(range(0, cfg.num_layers, g)):
         # shared attention with this invocation's cache
         h = jnp.concatenate([x, x0], -1)
@@ -221,9 +228,9 @@ def decode_step(cfg, params, cache, tokens):
         v_new = (h @ params["shared_attn/wv"]).reshape(B, 1, cfg.num_kv_heads, hd)
         q = apply_rope(q, sin, cos)
         k_new = apply_rope(k_new, sin, cos)
-        k_c = jax.lax.dynamic_update_slice(cache["k"][i], k_new, (0, pos % S, 0, 0))
-        v_c = jax.lax.dynamic_update_slice(cache["v"][i], v_new, (0, pos % S, 0, 0))
-        o = decode_attention(q, k_c, v_c, pos)
+        k_all = k_all.at[i, rows, rpos % S].set(k_new[:, 0])
+        v_all = v_all.at[i, rows, rpos % S].set(v_new[:, 0])
+        o = decode_attention(q, k_all[i], v_all[i], rpos)
         a = o.reshape(B, 1, -1) @ params["shared_attn/wo"]
         hm = rms_norm(a, params["shared_attn/mlp_norm/scale"], cfg.norm_eps)
         a = a + mlp(
@@ -233,36 +240,14 @@ def decode_step(cfg, params, cache, tokens):
             params["shared_attn/mlp/w_down"],
         )
         x = x + a @ params["shared_attn/out_proj/w"]
-        ks.append(k_c)
-        vs.append(v_c)
         # mamba group decode
-        hi = min(lo + g, cfg.num_layers)
-        sub = {n: a_[lo:hi] for n, a_ in stacked.items()}
-        sub["__conv"] = cache["conv"][lo:hi]
-        sub["__ssm"] = cache["ssm"][lo:hi]
-
-        def body(h, xs_l):
-            conv, ssm = xs_l.pop("__conv"), xs_l.pop("__ssm")
-            h, conv, ssm = mamba2.mamba_block_decode(cfg, xs_l, h, conv, ssm)
-            return h, (conv, ssm)
-
-        if scans_unrolled():
-            outs = []
-            for j in range(hi - lo):
-                x, o = body(x, {n: a_[j] for n, a_ in sub.items()})
-                outs.append(o)
-            conv = jnp.stack([o[0] for o in outs])
-            ssm = jnp.stack([o[1] for o in outs])
-        else:
-            x, (conv, ssm) = jax.lax.scan(body, x, sub)
-        convs.append(conv)
-        ssms.append(ssm)
+        x, conv, step = mamba2.decode_layers(
+            cfg, params, x, conv, cache["ssm"], lo, min(lo + g, cfg.num_layers)
+        )
+        steps.append(step)
     logits = mamba2.logits_fn(cfg, params, x)
-    new_cache = {
-        "k": jnp.stack(ks),
-        "v": jnp.stack(vs),
-        "conv": jnp.concatenate(convs),
-        "ssm": jnp.concatenate(ssms),
-        "pos": pos,
-    }
-    return logits, new_cache
+    ssm = mamba2.ssm_advance(
+        cache["ssm"], *(jnp.concatenate(s) for s in zip(*steps))
+    )
+    return logits, {"k": k_all, "v": v_all, "conv": conv, "ssm": ssm,
+                    "pos": pos}

@@ -30,7 +30,7 @@ from .common import (
 )
 from repro.dist.context import constrain
 from .moe import moe_block, shared_expert
-from .runtime import remat_wrap, scans_unrolled
+from .runtime import layer_loop, remat_wrap, scans_unrolled
 from .specs import ParamSpec
 
 # --------------------------------------------------------------------------
@@ -466,36 +466,44 @@ def prefill(cfg, params, batch, *, impl: str = "chunked", cache_len=None):
 
 
 def decode_step(cfg, params, cache, tokens):
-    """One decode step: tokens (B,1) + cache -> (logits (B,1,V), cache')."""
+    """One decode step: tokens (B,1) + cache -> (logits (B,1,V), cache').
+
+    ``pos`` is a scalar or one position per row (B,). The stacked K/V
+    caches are carried through the layer loop: each layer writes its new
+    token's K and V at each row's ``pos % S`` in place and reads its own
+    slice back."""
     B = tokens.shape[0]
     hd = cfg.resolved_head_dim
     pos = cache["pos"] + 1  # position being written
+    rpos = jnp.broadcast_to(pos, (B,))
+    rows = jnp.arange(B)
     x = jnp.take(params["embed/tokens"], tokens, axis=0)
-    sin, cos = rope_angles(pos[None].astype(jnp.int32), hd, cfg.rope_theta)
+    sin, cos = rope_angles(rpos[:, None], hd, cfg.rope_theta)
     prefix = "dec" if cfg.is_encdec else "blocks"
     stacked = _stacked_params(params, prefix)
     windows = _layer_windows(cfg)
-    homogeneous = len(set(windows)) == 1 and not scans_unrolled()
+    homogeneous = len(set(windows)) == 1
     S = cache["k"].shape[2]
 
-    def layer(x, p, k_c, v_c, window, xk=None, xv=None):
+    def layer(carry, p, i, window, xk=None, xv=None):
+        x, k_all, v_all = carry
         h = _norm(p, "attn_norm", x, cfg)
         q, k_new, v_new = _project_qkv(cfg, p, h)
         q = apply_rope(q, sin, cos)
         k_new = apply_rope(k_new, sin, cos)
-        k_c = jax.lax.dynamic_update_slice(k_c, k_new, (0, pos % S, 0, 0))
-        v_c = jax.lax.dynamic_update_slice(v_c, v_new, (0, pos % S, 0, 0))
+        k_all = k_all.at[i, rows, rpos % S].set(k_new[:, 0])
+        v_all = v_all.at[i, rows, rpos % S].set(v_new[:, 0])
+        k_c = jax.lax.dynamic_index_in_dim(k_all, i, 0, keepdims=False)
+        v_c = jax.lax.dynamic_index_in_dim(v_all, i, 0, keepdims=False)
         if window and window < S:
-            start = jnp.clip(pos - window + 1, 0, S - window)
-            kw = jax.lax.dynamic_slice(
-                k_c, (0, start, 0, 0), (B, window, k_c.shape[2], hd)
+            start = jnp.clip(rpos - window + 1, 0, S - window)
+            take = jax.vmap(
+                lambda c, s0: jax.lax.dynamic_slice_in_dim(c, s0, window, 0)
             )
-            vw = jax.lax.dynamic_slice(
-                v_c, (0, start, 0, 0), (B, window, v_c.shape[2], hd)
-            )
-            o = decode_attention(q, kw, vw, pos - start)
+            o = decode_attention(q, take(k_c, start), take(v_c, start),
+                                 rpos - start)
         else:
-            o = decode_attention(q, k_c, v_c, pos)
+            o = decode_attention(q, k_c, v_c, rpos)
         o = o.reshape(B, 1, -1) @ p["attn/wo"]
         if cfg.use_bias:
             o = o + p["attn/bo"]
@@ -507,34 +515,29 @@ def decode_step(cfg, params, cache, tokens):
             x = x + o.reshape(B, 1, -1) @ p["xattn/wo"]
         h = _norm(p, "mlp_norm", x, cfg)
         m, _ = _mlp_or_moe(cfg, p, h)
-        return x + m, k_c, v_c
+        return x + m, k_all, v_all
 
+    carry = (x, cache["k"], cache["v"])
     if homogeneous:
         xs = dict(stacked)
-        xs["__k"] = cache["k"]
-        xs["__v"] = cache["v"]
         if cfg.is_encdec:
+            # the cross caches are read-only: they ride as scan inputs
             xs["__xk"] = cache["xk"]
             xs["__xv"] = cache["xv"]
 
-        def body(x, xs_l):
-            k_c, v_c = xs_l.pop("__k"), xs_l.pop("__v")
+        def body(carry, xs_l, i):
             xk = xs_l.pop("__xk", None)
             xv = xs_l.pop("__xv", None)
-            x, k_c, v_c = layer(x, xs_l, k_c, v_c, windows[0], xk, xv)
-            return x, (k_c, v_c)
+            return layer(carry, xs_l, i, windows[0], xk, xv), None
 
-        x, (ks, vs) = jax.lax.scan(body, x, xs)
+        carry, _ = layer_loop(body, carry, xs)
     else:
-        ks_l, vs_l = [], []
         for i, w in enumerate(windows):
             p_i = {n: a[i] for n, a in stacked.items()}
             xk = cache["xk"][i] if cfg.is_encdec else None
             xv = cache["xv"][i] if cfg.is_encdec else None
-            x, k_c, v_c = layer(x, p_i, cache["k"][i], cache["v"][i], w, xk, xv)
-            ks_l.append(k_c)
-            vs_l.append(v_c)
-        ks, vs = jnp.stack(ks_l), jnp.stack(vs_l)
+            carry = layer(carry, p_i, i, w, xk, xv)
+    x, ks, vs = carry
 
     logits = logits_fn(cfg, params, x)
     new_cache = dict(cache)

@@ -431,7 +431,7 @@ class ServeEngine:
         ``cache_len`` (slot K/V rows need decode headroom past the
         prompt). Returns a ``scheduler.ServeLoopReport``.
 
-        ``temperature``/``top_k``/``sampling_seed`` switch the vmapped
+        ``temperature``/``top_k``/``sampling_seed`` switch the batched
         decode step from greedy argmax to temperature (optionally top-k)
         sampling with per-request PRNG keys; ``on_delta`` streams every
         decoded token as a ``scheduler.TokenDelta`` the step it is

@@ -10,14 +10,16 @@ retired independently at every decode step.
 
 The trick that keeps this jit-friendly across all three model families:
 every family's decode cache is a pytree whose array leaves carry batch at
-axis 1 (``(L, B, ...)``) with a scalar ``pos``. A slot is a B=1 cache; the
-pool stacks slot caches on a NEW leading axis (``(slots, L, 1, ...)``,
-``pos`` becomes ``(slots,)``) and one ``jax.vmap`` of ``models.decode_step``
-advances every slot in a single compiled dispatch — per-slot positions,
-per-slot RoPE phases, per-slot ring-buffer writes all fall out of the vmap.
-Admission splices a freshly prefilled B=1 cache into its slot with
-``dynamic_update_slice`` (donated, so it is an in-place row write on the
-device buffer).
+axis 1 (``(L, B, ...)``) beside ``pos``, and every ``decode_step`` takes
+``pos`` as one scalar or one position per row. The pool is that cache at
+batch = ``max_batch`` (``pos`` becomes ``(slots,)``), and one call of
+``models.decode_step`` on the whole pool advances every slot in a single
+compiled dispatch: per-slot positions, RoPE phases and ring-buffer writes
+come from the per-row ``pos``. The step updates the pool in place, in the
+layout it reads, so the donated pool is never copied whole.
+Admission splices a freshly prefilled B=1 cache into its slot at axis 1
+with ``dynamic_update_slice`` (donated, so it is an in-place row write on
+the device buffer).
 
 Host/device contract (this is where PR 6's satellite fix generalizes):
 the decode loop never syncs per step. Sampled tokens are scattered into a
@@ -173,10 +175,22 @@ class _Slot:
     first_token: int = -1            # prefill's token, host-side iff streaming
 
 
+#: Axis of the slot (batch) in every array leaf of a family's decode cache.
+_SLOT_AXIS = 1
+
+
+def _pool_shape(row_shape: tuple, slots: int) -> tuple:
+    """Shape of a pool leaf for ``slots`` rows, from a B=1 cache leaf:
+    the slot at axis 1 of each array, a scalar (``pos``) one per slot."""
+    if not row_shape:
+        return (slots,)
+    return row_shape[:_SLOT_AXIS] + (slots,) + row_shape[_SLOT_AXIS + 1:]
+
+
 def _programs(cfg, temp: float, top_k_n: int):
     """The slot scheduler's sampler and its two jitted programs: ``_step``
-    (vmap-advance every slot one token) and ``_admit`` (splice one B=1
-    cache row in)."""
+    (advance every slot one token) and ``_admit`` (splice one B=1 cache
+    row in)."""
 
     def _pick(logits, key, pos):
         # greedy vs sampled is a Python-static branch: temperature is
@@ -192,11 +206,8 @@ def _programs(cfg, temp: float, top_k_n: int):
         ).astype(jnp.int32)
 
     def _step(params, cache, toks, out_buf, steps, keys, active):
-        def one(c, t, k, s):
-            logits, c = models.decode_step(cfg, params, c, t)
-            return _pick(logits[0, -1], k, s), c
-
-        nxt, cache = jax.vmap(one)(cache, toks, keys, steps)
+        logits, cache = models.decode_step(cfg, params, cache, toks[:, :, 0])
+        nxt = jax.vmap(_pick)(logits[:, -1], keys, steps)
         nxt = jnp.where(active, nxt, 0)
         row = jnp.arange(out_buf.shape[0])
         idx = jnp.clip(steps, 0, out_buf.shape[1] - 1)
@@ -210,7 +221,8 @@ def _programs(cfg, temp: float, top_k_n: int):
                row_key, idx):
         cache = jax.tree_util.tree_map(
             lambda s, r: jax.lax.dynamic_update_slice_in_dim(
-                s, r[None].astype(s.dtype), idx, 0
+                s, jnp.atleast_1d(r).astype(s.dtype),
+                idx, _SLOT_AXIS if r.ndim else 0,
             ),
             cache,
             row_cache,
@@ -242,13 +254,13 @@ class SlotScheduler:
 
     Owns the stacked slot state (caches, next-token feeds, ``out_buf``,
     per-slot PRNG keys, step counters) and the two jitted programs that
-    mutate it: ``_step`` (vmap-advance every slot one token) and ``_admit``
+    mutate it: ``_step`` (advance every slot one token) and ``_admit``
     (splice one B=1 cache row in). Built lazily on first admission so the
     slot template matches whatever cache pytree the model family actually
     produces.
 
     Sampling: ``temperature > 0`` replaces greedy argmax with temperature
-    (optionally top-k) sampling *inside* the vmapped step. Token ``i`` of
+    (optionally top-k) sampling *inside* the step. Token ``i`` of
     request ``rid`` is drawn with ``fold_in(fold_in(base, rid), i)`` where
     ``base = PRNGKey(sampling_seed)`` — a pure function of (seed, rid, i),
     so a mid-flight admitted row never reuses a sibling slot's key stream,
@@ -307,7 +319,7 @@ class SlotScheduler:
     def _init_state(self, row_cache, max_new_cap: int) -> None:
         self.max_new_cap = max_new_cap
         cache = jax.tree_util.tree_map(
-            lambda r: jnp.zeros((self.slots,) + np.shape(r), r.dtype),
+            lambda r: jnp.zeros(_pool_shape(np.shape(r), self.slots), r.dtype),
             row_cache,
         )
         self._state = (
@@ -533,7 +545,7 @@ def run_serve_loop(
     the per-token frames the traffic plane forwards as PARTIAL frames.
 
     **Sampling**: ``temperature``/``top_k``/``sampling_seed`` select
-    temperature (optionally top-k) sampling in the vmapped decode step;
+    temperature (optionally top-k) sampling in the batched decode step;
     per-request PRNG keys are derived as ``fold_in(base, rid)`` so
     continuations are reproducible regardless of batch composition.
     All timestamps are ``time.monotonic()`` — the system-wide

@@ -582,7 +582,7 @@ def run_traffic(
 
     ``warmup_per_worker`` requests are pushed to every worker and drained
     BEFORE the measured phase, so each worker's jit compilation (prefill +
-    admit + vmapped step) happens off the clock — p50/p99 measure steady
+    admit + batched step) happens off the clock — p50/p99 measure steady
     state, not the first-request compile.
 
     All ring segments are unlinked before returning — and if this process
@@ -620,7 +620,7 @@ def run_traffic(
       verifies the reassembly byte-for-byte against each completion frame
       (``stream_gaps``/``stream_dup_frames``/``stream_mismatches``).
     * ``temperature``/``top_k``/``sampling_seed`` — temperature (top-k)
-      sampling in the workers' vmapped decode step; keys derive from the
+      sampling in the workers' batched decode step; keys derive from the
       rid, so re-routes and stream-vs-batch modes stay byte-identical.
     * ``priorities`` — optional per-request admission classes (array of
       ints, indexed by request); higher classes admit first, aged so

@@ -1,0 +1,168 @@
+"""The slot pool: every family's ``decode_step`` on a pool of rows at
+different positions, against each row stepped alone.
+
+The slot scheduler keeps its pool in the family's own cache layout (every
+array leaf ``(L, slots, ...)``, ``pos`` one per slot) and steps it with one
+call of ``models.decode_step``. A row of the pool must come out as it would
+alone at B=1 with a scalar ``pos``: the same logits and the same next cache.
+"""
+
+from __future__ import annotations
+
+from collections import deque
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from repro import models
+from repro.configs import get_config
+from repro.models.runtime import unroll_scans
+
+CACHE_LEN = 32
+PROMPT_LENS = (5, 13, 19)        # past the windowed archs' window of 16
+ENC_LEN = 8                      # one encoder length for every row
+
+FAMILIES = {
+    "dense": "starcoder2-3b",
+    "windowed": "gemma3-1b",
+    "moe": "olmoe-1b-7b",
+    "mamba2": "mamba2-370m",
+    "hybrid": "zamba2-7b",
+    "encdec": "seamless-m4t-large-v2",
+}
+
+
+def _rows(cfg, params, rng):
+    """One B=1 prefilled cache per prompt length, and a next token each."""
+    rows = []
+    for n in PROMPT_LENS:
+        batch = {"tokens": jnp.asarray(
+            rng.integers(0, cfg.vocab_size, (1, n), dtype=np.int32))}
+        if cfg.is_encdec:
+            batch["frames"] = jnp.asarray(
+                rng.standard_normal((1, ENC_LEN, cfg.d_model)), jnp.float32)
+        _, cache = models.prefill(cfg, params, batch, impl="naive",
+                                  cache_len=CACHE_LEN)
+        rows.append(cache)
+    toks = rng.integers(0, cfg.vocab_size, (len(rows), 1), dtype=np.int32)
+    return rows, jnp.asarray(toks)
+
+
+def _pool(rows):
+    """The rows as one pool: arrays joined at the batch axis (1), ``pos``
+    one per row."""
+    return jax.tree_util.tree_map(
+        lambda *r: jnp.stack(r) if r[0].ndim == 0 else jnp.concatenate(r, 1),
+        *rows,
+    )
+
+
+def _row(pool, b):
+    return jax.tree_util.tree_map(
+        lambda a: a[b] if a.ndim == 1 else a[:, b:b + 1], pool
+    )
+
+
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_pool_step_matches_each_row_alone(family):
+    cfg = get_config(FAMILIES[family], smoke=True)
+    if cfg.is_moe:
+        cfg = cfg.replace(capacity_factor=float(cfg.num_experts))
+    params = models.init_params(cfg, 0)
+    rows, toks = _rows(cfg, params, np.random.default_rng(7))
+    step = jax.jit(lambda p, c, t: models.decode_step(cfg, p, c, t))
+
+    logits, pool = step(params, _pool(rows), toks)
+    assert pool["pos"].shape == (len(rows),)
+    for b, row in enumerate(rows):
+        want_logits, want = step(params, row, toks[b:b + 1])
+        assert want["pos"].shape == ()
+        np.testing.assert_allclose(
+            np.asarray(logits[b:b + 1]), np.asarray(want_logits),
+            rtol=1e-5, atol=1e-5,
+        )
+        got = _row(pool, b)
+        assert int(got["pos"]) == int(want["pos"]) == PROMPT_LENS[b]
+        for name in want:
+            np.testing.assert_allclose(
+                np.asarray(got[name]), np.asarray(want[name]),
+                rtol=1e-5, atol=1e-5, err_msg=name,
+            )
+
+
+@pytest.mark.parametrize("family", ["mamba2", "hybrid", "windowed"])
+def test_unrolled_pool_step_matches_the_scan(family):
+    """The straight-line form (``unroll_scans``) writes the same pool."""
+    cfg = get_config(FAMILIES[family], smoke=True)
+    params = models.init_params(cfg, 0)
+    rows, toks = _rows(cfg, params, np.random.default_rng(8))
+    pool = _pool(rows)
+    a_logits, a = models.decode_step(cfg, params, pool, toks)
+    with unroll_scans():
+        b_logits, b = models.decode_step(cfg, params, pool, toks)
+    np.testing.assert_allclose(np.asarray(a_logits), np.asarray(b_logits),
+                               rtol=1e-5, atol=1e-5)
+    for name in a:
+        np.testing.assert_allclose(np.asarray(a[name]), np.asarray(b[name]),
+                                   rtol=1e-5, atol=1e-5, err_msg=name)
+
+
+# The tokens a sampled serve loop with admissions mid-flight produced when
+# the scheduler stepped a vmap of B=1 decode steps over a slot-major pool
+# (rids 0..4; temperature 0.7, top-k 8, sampling seed 5). Stepping the pool
+# in place in the family's own layout must not change one of them.
+SERVED = {
+    "dense": [[193, 125, 18, 200, 89, 202], [181, 131, 207],
+              [44, 99, 202, 27, 18], [191, 124, 207, 120],
+              [190, 64, 190, 64, 64, 81]],
+    "windowed": [[77, 78, 79, 223, 231, 197], [231, 191, 240],
+                 [70, 94, 124, 42, 223], [70, 77, 70, 104],
+                 [16, 223, 223, 63, 227, 215]],
+    "moe": [[0, 7, 36, 89, 89, 42], [89, 115, 174], [187, 250, 219, 187, 187],
+            [240, 168, 100, 168], [238, 36, 247, 16, 235, 36]],
+    "mamba2": [[41, 3, 104, 6, 80, 48], [67, 196, 53], [5, 142, 124, 13, 32],
+               [98, 252, 44, 196], [61, 85, 47, 179, 98, 2]],
+    "hybrid": [[136, 233, 87, 223, 12, 183], [168, 23, 114],
+               [215, 14, 148, 97, 2], [61, 7, 209, 89],
+               [59, 98, 139, 178, 122, 98]],
+    "encdec": [[193, 193, 98, 189, 98, 213], [50, 55, 58],
+               [62, 30, 158, 85, 58], [85, 85, 49, 120],
+               [49, 77, 62, 193, 98, 193]],
+}
+
+
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_midflight_admissions_serve_the_same_tokens(family):
+    """Five requests of different lengths through three slots, arriving
+    every other poll, so rows join a pool whose other rows sit at other
+    positions (an encoder-decoder's requests share one prompt length: its
+    cross caches are as long as the prompt)."""
+    from repro.serve import STOP, Request, ServeEngine
+
+    cfg = get_config(FAMILIES[family], smoke=True)
+    engine = ServeEngine(cfg, models.init_params(cfg, 0),
+                         cache_len=CACHE_LEN, impl="naive")
+    rng = np.random.default_rng(11)
+    lens = (9,) * 5 if cfg.is_encdec else (5, 9, 13, 7, 11)
+    pending = deque(
+        Request(rid=i, max_new_tokens=m,
+                prompt=rng.integers(0, cfg.vocab_size, n, dtype=np.int32))
+        for i, (n, m) in enumerate(zip(lens, (6, 3, 5, 4, 6)))
+    )
+    polls = {"n": 0}
+
+    def trickle():
+        polls["n"] += 1
+        if not pending:
+            return STOP
+        return pending.popleft() if polls["n"] % 2 else None
+
+    done = {}
+    report = engine.serve_loop(
+        trickle, lambda c: done.setdefault(c.rid, c), max_batch=3,
+        max_queue=2, temperature=0.7, top_k=8, sampling_seed=5,
+    )
+    assert report.completed == 5 and report.peak_active <= 3
+    assert [done[i].tokens.tolist() for i in range(5)] == SERVED[family]

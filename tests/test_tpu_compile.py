@@ -68,21 +68,33 @@ def test_mamba2_prefill_compiles_for_v5e(one_chip, engine):
     _fits(compiled)
 
 
-def test_mamba2_slot_decode_step_compiles_for_v5e(one_chip, engine):
-    """The served decode program: SlotScheduler's vmapped step over 4
-    slots, each a B=1 cache row."""
+# (arch, slots, cache_len) of the benchmark's two cells
+SLOT_POOLS = {
+    "mamba2-370m": (64, 512 + 16),
+    "starcoder2-3b": (32, 4096),
+}
+
+
+@pytest.mark.parametrize("arch", sorted(SLOT_POOLS))
+def test_slot_decode_step_compiles_for_v5e(one_chip, arch):
+    """The served decode program: SlotScheduler's step over the slot pool,
+    the family's cache at batch = slots (every array leaf ``(L, slots,
+    ...)``, ``pos`` one per slot). The step updates the donated pool in
+    place: its scratch stays under one layer's slice of the pool, and the
+    pool is aliased from input to output, so nothing copies it whole."""
+    from repro.serve import ServeEngine
     from repro.serve.scheduler import SlotScheduler
 
-    cfg, slots = engine.cfg, 4
+    slots, cache_len = SLOT_POOLS[arch]
+    cfg = get_config(arch)
+    engine = ServeEngine(cfg, params=None, cache_len=cache_len)
     sched = SlotScheduler(engine, max_batch=slots)
-    row, _ = models.cache_spec(cfg, 1, engine.cache_len)
-    cache = jax.tree_util.tree_map(
-        lambda s: jax.ShapeDtypeStruct((slots,) + s.shape, s.dtype), row
-    )
+    pool, _ = models.cache_spec(cfg, slots, cache_len)
+    pool["pos"] = jax.ShapeDtypeStruct((slots,), jnp.int32)
     key = jax.random.PRNGKey(0)
     args = _on(one_chip, (
         models.abstract(cfg),
-        cache,
+        pool,
         jax.ShapeDtypeStruct((slots, 1, 1), jnp.int32),
         jax.ShapeDtypeStruct((slots, 16), jnp.int32),
         jax.ShapeDtypeStruct((slots,), jnp.int32),
@@ -91,6 +103,14 @@ def test_mamba2_slot_decode_step_compiles_for_v5e(one_chip, engine):
     ))
     compiled = sched._step_fn.lower(*args).compile()
     _fits(compiled)
+    layers = [a for a in pool.values() if a.ndim > 1]
+    pool_bytes = sum(a.size * a.dtype.itemsize for a in pool.values())
+    layer_bytes = sum(a.size * a.dtype.itemsize // a.shape[0] for a in layers)
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < layer_bytes, (mem.temp_size_in_bytes,
+                                                   layer_bytes)
+    assert mem.alias_size_in_bytes >= pool_bytes, (mem.alias_size_in_bytes,
+                                                   pool_bytes)
 
 
 def _kernel_cases():
