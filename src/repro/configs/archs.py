@@ -68,7 +68,9 @@ STARCODER2_3B = ModelConfig(
     rope_theta=999_999.4,
 )
 
-# [arXiv:2409.02060; hf] — 64 experts, top-8, MHA
+# [arXiv:2409.02060; hf:allenai/OLMoE-1B-7B-0924] — 64 experts, top-8 of
+# a softmax over all 64, not renormalised; MHA with q/k RMSNorms over the
+# whole projected width; untied head
 OLMOE_1B_7B = ModelConfig(
     name="olmoe-1b-7b",
     family="moe",
@@ -80,7 +82,12 @@ OLMOE_1B_7B = ModelConfig(
     vocab_size=50304,
     num_experts=64,
     experts_per_token=8,
+    norm_topk_prob=False,
     qk_norm=True,
+    qk_norm_width="full",
+    rope_theta=10_000.0,
+    norm_eps=1e-5,
+    tie_embeddings=False,
 )
 
 # [hf:Qwen/Qwen1.5-MoE-A2.7B; hf] — 4 shared + 60 routed, top-4

@@ -26,7 +26,8 @@ class ModelConfig:
 
     # attention flavour
     qkv_bias: bool = False           # qwen1.5 QKV bias
-    qk_norm: bool = False            # gemma3 / chameleon
+    qk_norm: bool = False            # gemma3 / chameleon / olmoe
+    qk_norm_width: str = "head"      # head: per head | full: whole projection
     rope_theta: float = 10_000.0
     sliding_window: int = 0          # 0 = full attention everywhere
     global_every: int = 0            # gemma3: every Nth layer is global
@@ -39,7 +40,11 @@ class ModelConfig:
     num_experts: int = 0
     experts_per_token: int = 0
     num_shared_experts: int = 0      # qwen2-moe: shared expert = n * d_ff wide
-    capacity_factor: float = 1.25
+    norm_topk_prob: bool = True      # renormalise the top-k weights to sum 1
+    # expert parallelism: this chip holds experts first_expert ..
+    # first_expert + experts_held - 1 (0: all) and routes over all of them
+    experts_held: int = 0
+    first_expert: int = 0
 
     # SSM (mamba2 / zamba2)
     ssm_state: int = 0
@@ -73,6 +78,10 @@ class ModelConfig:
     @property
     def is_moe(self) -> bool:
         return self.num_experts > 0
+
+    @property
+    def held_experts(self) -> int:
+        return self.experts_held or self.num_experts
 
     @property
     def is_ssm(self) -> bool:

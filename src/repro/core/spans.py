@@ -13,7 +13,10 @@ trace's clock. It also appends a ``Span`` record to a bounded in-memory
 ring, stamped with ``time.monotonic_ns()``: the clock the serve loop and
 its callers already use, which a trace maps onto its own by one offset.
 Parent links come from a per-thread stack of open spans; attributes stay
-few (a request id on admission, ``n_active`` on a decode step).
+few (a request id on admission, ``n_active`` on a decode step, the
+expert layer's counters on a prefill or a step of a model that has one).
+``annotate`` adds attributes to an open span, or to a closed one opened
+with some: a counter that a program returns is read at a later sync.
 
 The first span also registers one process-wide ``jax.monitoring``
 listener. It records every compile event with its monotonic end, its
@@ -179,6 +182,14 @@ class _Open:
         self._stack.pop()
         self._rec._spans.append((self._index, self._name, self._t0, t1,
                                  self._parent, self._attrs))
+
+    def annotate(self, **attrs) -> None:
+        """Add attributes to this span's record. After the span has closed
+        this reaches its record only where it was opened with attributes:
+        the record holds that dict."""
+        if self._attrs is None:
+            self._attrs = {}
+        self._attrs.update(attrs)
 
 
 def _stats(pairs) -> dict:

@@ -6,12 +6,15 @@
     flash_attention  — blockwise online-softmax attention (causal / GQA /
                        sliding window) for train + prefill
     rmsnorm          — fused norm
+    moe_gmm          — grouped matmul over a chip's held experts, rows
+                       sorted by expert, group sizes from the routing
+                       (the expert layer's dropless SwiGLU)
 
 Each package: <name>.py (pl.pallas_call + BlockSpec), ops.py (jit wrapper),
 ref.py (pure-jnp oracle). Validated on CPU with interpret=True; compiled
 via Mosaic on TPU.
 """
 
-from . import flash_attention, paged_reloc_copy, rmsnorm
+from . import flash_attention, moe_gmm, paged_reloc_copy, rmsnorm
 
-__all__ = ["flash_attention", "paged_reloc_copy", "rmsnorm"]
+__all__ = ["flash_attention", "moe_gmm", "paged_reloc_copy", "rmsnorm"]

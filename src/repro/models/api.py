@@ -5,8 +5,8 @@
     init_params_np(cfg, seed)         -> {name: np.ndarray}  (no JAX backend)
     loss_fn(cfg, params, batch)       -> scalar
     forward(cfg, params, batch)       -> (logits, aux)
-    prefill(cfg, params, batch)       -> (logits, cache)
-    decode_step(cfg, params, cache, tokens) -> (logits, cache)
+    prefill(cfg, params, batch)       -> (logits, cache[, counts])
+    decode_step(cfg, params, cache, tokens) -> (logits, cache[, counts])
     cache_spec / init_cache(cfg, B, S)
     manifest_refs(cfg)                -> [SymbolRef]  (stable-linking imports)
     input_specs(cfg, shape)           -> {name: ShapeDtypeStruct} (dry-run)
@@ -55,12 +55,18 @@ def loss_fn(cfg, params, batch, *, impl="chunked"):
     return _mod(cfg).loss_fn(cfg, params, batch, impl=impl)
 
 
-def prefill(cfg, params, batch, *, impl="chunked", cache_len=None):
-    return _mod(cfg).prefill(cfg, params, batch, impl=impl, cache_len=cache_len)
+def prefill(cfg, params, batch, *, impl="chunked", cache_len=None, **moe):
+    """(logits, cache); a model with routed experts (transformer family)
+    also takes ``counters=True`` and then returns the expert layers'
+    counts (``moe.COUNTERS``, summed over layers) as well."""
+    return _mod(cfg).prefill(cfg, params, batch, impl=impl,
+                             cache_len=cache_len, **moe)
 
 
-def decode_step(cfg, params, cache, tokens):
-    return _mod(cfg).decode_step(cfg, params, cache, tokens)
+def decode_step(cfg, params, cache, tokens, **moe):
+    """(logits, cache); a model with routed experts also takes ``active``
+    (B,), the rows its expert layers route, and ``counters``."""
+    return _mod(cfg).decode_step(cfg, params, cache, tokens, **moe)
 
 
 def cache_spec(cfg, batch, seq_len):

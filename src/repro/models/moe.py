@@ -1,86 +1,87 @@
-"""Mixture-of-Experts block: capacity-based dispatch (GShard-style) via
-scatter/gather, expert-parallel friendly.
+"""Mixture-of-Experts block: dropless routing over every expert, computed
+for the experts this chip holds.
 
-Dispatch avoids the O(S*k*E*C) one-hot einsum: slot positions come from a
-one-hot cumsum, tokens are scattered into an (E, C, d) buffer per batch row,
-experts run as a single batched matmul over the E axis (shardable on the
-``model``/expert axis), and outputs gather back with combine weights.
-FLOP count is the *active*-expert count (k experts/token + shared), so the
-roofline's 6*N_active*D model holds.
+The router scores all ``E`` experts and each token keeps its top ``k``
+(renormalised where the config asks). Under expert parallelism a chip
+holds ``E_h`` consecutive experts from ``first``; the (token, expert)
+pairs that land on them are sorted by expert, and a grouped matmul
+(``kernels/moe_gmm``) runs each held expert's SwiGLU over exactly its
+rows: no capacity, nothing dropped, no work for pairs routed to other
+chips or for experts no token chose. The results are combined by the
+routing weights. Pairs routed elsewhere add nothing here: that partial
+output is what goes on to the residual (the exchange that would bring the
+other chips' parts is not part of this layer).
 
-Returns the standard switch/load-balance auxiliary loss.
+Returns the output, the Switch load-balance auxiliary loss over all ``E``
+experts, and the layer's counters (``COUNTERS``).
 """
 
 from __future__ import annotations
 
-import math
-
 import jax
 import jax.numpy as jnp
-import numpy as np
+
+from repro.kernels.moe_gmm import gmm
+
+#: The expert layer's counters, in the order of its ``counts`` array: the
+#: (token, expert) pairs routed to held experts, and the held experts that
+#: at least one pair chose.
+COUNTERS = ("moe_rows", "moe_experts_hit")
+
+
+def no_counts() -> jax.Array:
+    return jnp.zeros((len(COUNTERS),), jnp.int32)
 
 
 def moe_block(
     x: jax.Array,                 # (B, S, d)
     router_w: jax.Array,          # (d, E)
-    w_gate: jax.Array,            # (E, d, ff)
-    w_up: jax.Array,              # (E, d, ff)
-    w_down: jax.Array,            # (E, ff, d)
+    w_gate: jax.Array,            # (E_h, d, ff)
+    w_up: jax.Array,              # (E_h, d, ff)
+    w_down: jax.Array,            # (E_h, ff, d)
     *,
     k: int,
-    capacity_factor: float = 1.25,
-) -> tuple[jax.Array, jax.Array]:
+    first: int = 0,
+    norm_topk: bool = True,
+    rows: jax.Array | None = None,   # (B,) bool: rows that route; None: all
+) -> tuple[jax.Array, jax.Array, jax.Array]:
     B, S, d = x.shape
-    E = router_w.shape[-1]
-    logits = (x @ router_w.astype(x.dtype)).astype(jnp.float32)   # (B,S,E)
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_p, top_e = jax.lax.top_k(probs, k)                        # (B,S,k)
-    top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
-
-    capacity = max(1, int(math.ceil(k * S / E * capacity_factor)))
-    capacity = min(capacity, S * k)
-
-    # ---- slot positions: cumsum of expert one-hots over the S*k slot axis
-    e_flat = top_e.reshape(B, S * k)                              # (B, S*k)
-    oh = jax.nn.one_hot(e_flat, E, dtype=jnp.int32)               # (B,S*k,E)
-    pos = (jnp.cumsum(oh, axis=1) * oh).sum(-1) - 1               # (B, S*k)
-    keep = pos < capacity
-    pos_c = jnp.clip(pos, 0, capacity - 1)
-
-    # ---- scatter tokens into (E, C, d) per batch row
-    x_slots = jnp.broadcast_to(x[:, :, None, :], (B, S, k, d)).reshape(B, S * k, d)
-
-    def scatter_row(xs, e, p, kp):
-        buf = jnp.zeros((E, capacity, d), xs.dtype)
-        return buf.at[e, p].add(xs * kp[:, None])
-
-    buf = jax.vmap(scatter_row)(x_slots, e_flat, pos_c, keep.astype(x.dtype))
-
-    # ---- expert FFN: batched over E (expert-parallel shardable)
-    wg = w_gate.astype(x.dtype)
-    wu = w_up.astype(x.dtype)
-    wd = w_down.astype(x.dtype)
-    h = jax.nn.silu(jnp.einsum("becd,edf->becf", buf, wg)) * jnp.einsum(
-        "becd,edf->becf", buf, wu
-    )
-    y = jnp.einsum("becf,efd->becd", h, wd)                       # (B,E,C,d)
-
-    # ---- gather back with combine weights
-    def gather_row(yb, e, p):
-        return yb[e, p]                                           # (S*k, d)
-
-    out_slots = jax.vmap(gather_row)(y, e_flat, pos_c)
-    w_slots = (top_p.reshape(B, S * k) * keep).astype(x.dtype)
-    out = (out_slots * w_slots[:, :, None]).reshape(B, S, k, d).sum(2)
+    E, E_h = router_w.shape[-1], w_gate.shape[0]
+    T, P = B * S, B * S * k
+    with jax.named_scope("moe"):
+        xt = x.reshape(T, d)
+        logits = (xt @ router_w.astype(x.dtype)).astype(jnp.float32)
+        probs = jax.nn.softmax(logits, axis=-1)                   # (T, E)
+        top_p, top_e = jax.lax.top_k(probs, k)                    # (T, k)
+        if norm_topk:
+            top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
+        local = top_e - first
+        held = (local >= 0) & (local < E_h)
+        if rows is not None:
+            held &= jnp.repeat(rows, S)[:, None]
+        # pairs sorted by held expert; every other pair after them
+        key = jnp.where(held, local, E_h).reshape(P)
+        order = jnp.argsort(key, stable=True)
+        group_sizes = jnp.bincount(key, length=E_h + 1)[:E_h].astype(jnp.int32)
+        xs = jnp.take(xt, order // k, axis=0)                    # (P, d)
+        h = (jax.nn.silu(gmm(xs, w_gate.astype(x.dtype), group_sizes))
+             * gmm(xs, w_up.astype(x.dtype), group_sizes))
+        ys = gmm(h, w_down.astype(x.dtype), group_sizes)         # (P, d)
+        n_held = group_sizes.sum()
+        ys = jnp.where((jnp.arange(P) < n_held)[:, None], ys, 0)
+        back = jnp.zeros((P,), jnp.int32).at[order].set(
+            jnp.arange(P, dtype=jnp.int32))
+        y = jnp.take(ys, back, axis=0).reshape(T, k, d)
+        w = jnp.where(held, top_p, 0.0)
+        out = jnp.einsum("tkd,tk->td", y.astype(jnp.float32), w)
+        out = out.astype(x.dtype).reshape(B, S, d)
+        counts = jnp.stack([n_held, (group_sizes > 0).sum()]).astype(jnp.int32)
 
     # ---- load-balance aux loss (Switch): E * sum_e f_e * P_e
-    me = probs.mean(axis=(0, 1))                                  # (E,)
-    ce = (
-        jax.nn.one_hot(top_e[..., 0], E, dtype=jnp.float32)
-        .mean(axis=(0, 1))
-    )
+    me = probs.mean(axis=0)                                       # (E,)
+    ce = jax.nn.one_hot(top_e[:, 0], E, dtype=jnp.float32).mean(axis=0)
     aux = E * jnp.sum(me * ce)
-    return out, aux
+    return out, aux, counts
 
 
 def shared_expert(

@@ -29,7 +29,7 @@ from .common import (
     rope_angles,
 )
 from repro.dist.context import constrain
-from .moe import moe_block, shared_expert
+from .moe import moe_block, no_counts, shared_expert
 from .runtime import layer_loop, remat_wrap, scans_unrolled
 from .specs import ParamSpec
 
@@ -61,7 +61,10 @@ def _attn_specs(cfg, d_in: int, d_out: int) -> dict[str, ParamSpec]:
         s["attn/bv"] = ParamSpec((KV * hd,), dt, ("kv_heads",), "zeros")
     if cfg.use_bias:
         s["attn/bo"] = ParamSpec((d_out,), dt, ("embed",), "zeros")
-    if cfg.qk_norm:
+    if cfg.qk_norm and cfg.qk_norm_width == "full":
+        s["attn/q_norm"] = ParamSpec((H * hd,), dt, ("heads",), "ones")
+        s["attn/k_norm"] = ParamSpec((KV * hd,), dt, ("kv_heads",), "ones")
+    elif cfg.qk_norm:
         s["attn/q_norm"] = ParamSpec((hd,), dt, ("head_dim",), "ones")
         s["attn/k_norm"] = ParamSpec((hd,), dt, ("head_dim",), "ones")
     return s
@@ -70,17 +73,18 @@ def _attn_specs(cfg, d_in: int, d_out: int) -> dict[str, ParamSpec]:
 def _mlp_specs(cfg, d: int) -> dict[str, ParamSpec]:
     dt, ff = cfg.dtype, cfg.d_ff
     if cfg.is_moe:
-        E = cfg.num_experts
+        # the router scores every expert; this chip holds E_h of them
+        E, E_h = cfg.num_experts, cfg.held_experts
         s = {
             "router/w": ParamSpec((d, E), dt, ("embed", "experts"), "fan_in"),
             "experts/w_gate": ParamSpec(
-                (E, d, ff), dt, ("experts", "embed", "mlp"), "fan_in"
+                (E_h, d, ff), dt, ("experts", "embed", "mlp"), "fan_in"
             ),
             "experts/w_up": ParamSpec(
-                (E, d, ff), dt, ("experts", "embed", "mlp"), "fan_in"
+                (E_h, d, ff), dt, ("experts", "embed", "mlp"), "fan_in"
             ),
             "experts/w_down": ParamSpec(
-                (E, ff, d), dt, ("experts", "mlp", "embed"), "fan_in"
+                (E_h, ff, d), dt, ("experts", "mlp", "embed"), "fan_in"
             ),
         }
         if cfg.num_shared_experts:
@@ -167,10 +171,14 @@ def _project_qkv(cfg, p, x, *, prefix="attn"):
         q = q + p[f"{prefix}/bq"]
         k = k + p[f"{prefix}/bk"]
         v = v + p[f"{prefix}/bv"]
+    full = cfg.qk_norm and cfg.qk_norm_width == "full"
+    if full:    # olmoe: over the whole projection, before the split
+        q = rms_norm(q, p[f"{prefix}/q_norm"], cfg.norm_eps)
+        k = rms_norm(k, p[f"{prefix}/k_norm"], cfg.norm_eps)
     q = q.reshape(B, S, H, hd)
     k = k.reshape(B, S, KV, hd)
     v = v.reshape(B, S, KV, hd)
-    if cfg.qk_norm:
+    if cfg.qk_norm and not full:
         q = rms_norm(q, p[f"{prefix}/q_norm"], cfg.norm_eps)
         k = rms_norm(k, p[f"{prefix}/k_norm"], cfg.norm_eps)
     return q, k, v
@@ -190,17 +198,20 @@ def _self_attention(cfg, p, x, sin, cos, *, window, impl, q_offset=0):
     return o, k, v
 
 
-def _mlp_or_moe(cfg, p, x):
-    """Returns (out, aux_loss)."""
+def _mlp_or_moe(cfg, p, x, rows=None):
+    """Returns (out, aux_loss, the expert layer's counts). ``rows`` (B,)
+    marks the rows an expert layer routes (None: all)."""
     if cfg.is_moe:
-        out, aux = moe_block(
+        out, aux, counts = moe_block(
             x,
             p["router/w"],
             p["experts/w_gate"],
             p["experts/w_up"],
             p["experts/w_down"],
             k=cfg.experts_per_token,
-            capacity_factor=cfg.capacity_factor,
+            first=cfg.first_expert,
+            norm_topk=cfg.norm_topk_prob,
+            rows=rows,
         )
         if cfg.num_shared_experts:
             out = out + shared_expert(
@@ -210,7 +221,7 @@ def _mlp_or_moe(cfg, p, x):
                 p["shared/w_down"],
                 p["shared/gate"],
             )
-        return out, aux
+        return out, aux, counts
     return (
         mlp(
             x,
@@ -222,6 +233,7 @@ def _mlp_or_moe(cfg, p, x):
             b_down=p.get("mlp/b_down"),
         ),
         jnp.float32(0.0),
+        no_counts(),
     )
 
 
@@ -264,9 +276,9 @@ def _block(cfg, p, x, sin, cos, *, window, impl, enc_out=None,
         o = o.reshape(B, S, -1) @ p["xattn/wo"]
         x = x + o
     h = _norm(p, "mlp_norm", x, cfg)
-    m, aux = _mlp_or_moe(cfg, p, h)
+    m, aux, counts = _mlp_or_moe(cfg, p, h)
     x = x + m
-    return (x, aux, (k, v)) if collect_kv else (x, aux, None)
+    return x, aux, counts, ((k, v) if collect_kv else None)
 
 
 def _stacked_params(params: dict, prefix: str) -> dict:
@@ -297,27 +309,31 @@ def run_stack(
     collect_kv=False,
     remat=True,
 ):
-    """Run a layer stack; homogeneous window -> lax.scan, else unrolled."""
+    """Run a layer stack; homogeneous window -> lax.scan, else unrolled.
+    Returns (x, aux loss, (K, V) per layer or None, the expert layers'
+    counts summed over layers)."""
     stacked = _stacked_params(params, prefix)
     windows = _layer_windows(cfg) if prefix != "enc" else [0] * cfg.encoder_layers
     homogeneous = len(set(windows)) == 1 and not scans_unrolled()
 
     if homogeneous:
         def body(carry, xs):
-            h, aux = carry
-            h2, aux_l, kv = _block(
+            h, aux, counts = carry
+            h2, aux_l, counts_l, kv = _block(
                 cfg, xs, h, sin, cos, window=windows[0], impl=impl,
                 enc_out=enc_out, collect_kv=collect_kv,
             )
-            return (h2, aux + aux_l), kv
+            return (h2, aux + aux_l, counts + counts_l), kv
 
         if remat:
             body = remat_wrap(body, cfg)
-        (x, aux), kvs = jax.lax.scan(body, (x, jnp.float32(0.0)), stacked)
-        return x, aux, kvs
+        (x, aux, counts), kvs = jax.lax.scan(
+            body, (x, jnp.float32(0.0), no_counts()), stacked)
+        return x, aux, kvs, counts
 
     # heterogeneous (gemma3 local:global): unrolled, static per-layer window
     aux = jnp.float32(0.0)
+    counts = no_counts()
     ks, vs = [], []
     L = len(windows)
     for i in range(L):
@@ -328,13 +344,14 @@ def run_stack(
         )
         if remat:
             blk = remat_wrap(blk, cfg)
-        x, aux_l, kv = blk(x, sin, cos)
+        x, aux_l, counts_l, kv = blk(x, sin, cos)
         aux = aux + aux_l
+        counts = counts + counts_l
         if collect_kv:
             ks.append(kv[0])
             vs.append(kv[1])
     kvs = (jnp.stack(ks), jnp.stack(vs)) if collect_kv else None
-    return x, aux, kvs
+    return x, aux, kvs, counts
 
 
 # --------------------------------------------------------------------------
@@ -358,7 +375,7 @@ def _encode(cfg, params, frames, impl):
         x = x @ params["frontend/proj"]
     S = x.shape[1]
     sin, cos = rope_angles(jnp.arange(S), cfg.resolved_head_dim, cfg.rope_theta)
-    x, _, _ = run_stack(cfg, params, "enc", x, sin, cos, impl=impl)
+    x, _, _, _ = run_stack(cfg, params, "enc", x, sin, cos, impl=impl)
     return _norm(params, "enc_final_norm", x, cfg)
 
 
@@ -381,11 +398,11 @@ def forward(cfg, params, batch, *, impl: str = "chunked"):
     enc_out = None
     if cfg.is_encdec:
         enc_out = _encode(cfg, params, batch["frames"], impl)
-        x, aux, _ = run_stack(
+        x, aux, _, _ = run_stack(
             cfg, params, "dec", x, sin, cos, impl=impl, enc_out=enc_out
         )
     else:
-        x, aux, _ = run_stack(cfg, params, "blocks", x, sin, cos, impl=impl)
+        x, aux, _, _ = run_stack(cfg, params, "blocks", x, sin, cos, impl=impl)
     return logits_fn(cfg, params, x), aux
 
 
@@ -421,8 +438,11 @@ def init_cache(cfg, batch: int, seq_len: int):
     return {k: jnp.zeros(s.shape, s.dtype) for k, s in shapes.items()}
 
 
-def prefill(cfg, params, batch, *, impl: str = "chunked", cache_len=None):
-    """Process a prompt; returns (last-position logits, filled cache)."""
+def prefill(cfg, params, batch, *, impl: str = "chunked", cache_len=None,
+            counters: bool = False):
+    """Process a prompt; returns (last-position logits, filled cache), and
+    with ``counters`` the expert layers' counts summed over layers
+    (``moe.COUNTERS``; zeros for a model with none)."""
     tokens = batch["tokens"]
     B, S = tokens.shape
     cache_len = cache_len or S
@@ -432,7 +452,7 @@ def prefill(cfg, params, batch, *, impl: str = "chunked", cache_len=None):
     extra = {}
     if cfg.is_encdec:
         enc_out = _encode(cfg, params, batch["frames"], impl)
-        x, _, kvs = run_stack(
+        x, _, kvs, counts = run_stack(
             cfg, params, "dec", x, sin, cos, impl=impl, enc_out=enc_out,
             collect_kv=True,
         )
@@ -452,7 +472,7 @@ def prefill(cfg, params, batch, *, impl: str = "chunked", cache_len=None):
         xks, xvs = jax.vmap(xkv)(stacked["xattn/wk"], stacked["xattn/wv"])
         extra = {"xk": xks, "xv": xvs}
     else:
-        x, _, kvs = run_stack(
+        x, _, kvs, counts = run_stack(
             cfg, params, "blocks", x, sin, cos, impl=impl, collect_kv=True
         )
     ks, vs = kvs
@@ -462,16 +482,19 @@ def prefill(cfg, params, batch, *, impl: str = "chunked", cache_len=None):
         vs = jnp.pad(vs, ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
     cache = {"k": ks, "v": vs, "pos": jnp.int32(S - 1), **extra}
     logits = logits_fn(cfg, params, x[:, -1:, :])
-    return logits, cache
+    return (logits, cache, counts) if counters else (logits, cache)
 
 
-def decode_step(cfg, params, cache, tokens):
-    """One decode step: tokens (B,1) + cache -> (logits (B,1,V), cache').
+def decode_step(cfg, params, cache, tokens, *, active=None,
+                counters: bool = False):
+    """One decode step: tokens (B,1) + cache -> (logits (B,1,V), cache'),
+    and with ``counters`` the expert layers' counts summed over layers.
 
     ``pos`` is a scalar or one position per row (B,). The stacked K/V
     caches are carried through the layer loop: each layer writes its new
     token's K and V at each row's ``pos % S`` in place and reads its own
-    slice back."""
+    slice back. ``active`` (B,) bool marks the rows an expert layer routes:
+    a free slot's row costs its experts nothing (None: every row)."""
     B = tokens.shape[0]
     hd = cfg.resolved_head_dim
     pos = cache["pos"] + 1  # position being written
@@ -514,8 +537,8 @@ def decode_step(cfg, params, cache, tokens):
             o = decode_attention(q2, xk, xv, jnp.int32(xk.shape[1] - 1))
             x = x + o.reshape(B, 1, -1) @ p["xattn/wo"]
         h = _norm(p, "mlp_norm", x, cfg)
-        m, _ = _mlp_or_moe(cfg, p, h)
-        return x + m, k_all, v_all
+        m, _, counts = _mlp_or_moe(cfg, p, h, rows=active)
+        return (x + m, k_all, v_all), counts
 
     carry = (x, cache["k"], cache["v"])
     if homogeneous:
@@ -528,18 +551,21 @@ def decode_step(cfg, params, cache, tokens):
         def body(carry, xs_l, i):
             xk = xs_l.pop("__xk", None)
             xv = xs_l.pop("__xv", None)
-            return layer(carry, xs_l, i, windows[0], xk, xv), None
+            return layer(carry, xs_l, i, windows[0], xk, xv)
 
-        carry, _ = layer_loop(body, carry, xs)
+        carry, counts = layer_loop(body, carry, xs)
+        counts = counts.sum(0)
     else:
+        counts = no_counts()
         for i, w in enumerate(windows):
             p_i = {n: a[i] for n, a in stacked.items()}
             xk = cache["xk"][i] if cfg.is_encdec else None
             xv = cache["xv"][i] if cfg.is_encdec else None
-            carry = layer(carry, p_i, i, w, xk, xv)
+            carry, counts_l = layer(carry, p_i, i, w, xk, xv)
+            counts = counts + counts_l
     x, ks, vs = carry
 
     logits = logits_fn(cfg, params, x)
     new_cache = dict(cache)
     new_cache.update({"k": ks, "v": vs, "pos": pos})
-    return logits, new_cache
+    return (logits, new_cache, counts) if counters else (logits, new_cache)

@@ -122,9 +122,12 @@ class ServeEngine:
         self.cache_len = cache_len
 
         def _prefill(params, batch):
+            # (logits, cache), and for a model with an expert layer its
+            # counts (models.moe.COUNTERS)
+            moe = dict(counters=True) if cfg.is_moe else {}
             return models.prefill(
                 cfg, params, batch, impl=impl,
-                cache_len=cache_len or None,
+                cache_len=cache_len or None, **moe,
             )
 
         def _decode(params, cache, tokens):
@@ -378,7 +381,7 @@ class ServeEngine:
                 jnp.dtype(self.cfg.dtype),
             )
         t0 = time.perf_counter()
-        logits, cache = self._prefill(self.params, batch)
+        logits, cache = self._prefill(self.params, batch)[:2]
         tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)[:, None]
         jax.block_until_ready(tok)
         stats.prefill_s = time.perf_counter() - t0

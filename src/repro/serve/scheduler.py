@@ -189,8 +189,9 @@ def _pool_shape(row_shape: tuple, slots: int) -> tuple:
 
 def _programs(cfg, temp: float, top_k_n: int):
     """The slot scheduler's sampler and its two jitted programs: ``_step``
-    (advance every slot one token) and ``_admit`` (splice one B=1 cache
-    row in)."""
+    (advance every slot one token; for a model with an expert layer it
+    also returns the layers' counters, ``models.moe.COUNTERS``) and
+    ``_admit`` (splice one B=1 cache row in)."""
 
     def _pick(logits, key, pos):
         # greedy vs sampled is a Python-static branch: temperature is
@@ -206,7 +207,11 @@ def _programs(cfg, temp: float, top_k_n: int):
         ).astype(jnp.int32)
 
     def _step(params, cache, toks, out_buf, steps, keys, active):
-        logits, cache = models.decode_step(cfg, params, cache, toks[:, :, 0])
+        # a model with an expert layer also returns its counts; a free
+        # slot's row costs the experts nothing
+        moe = dict(active=active, counters=True) if cfg.is_moe else {}
+        logits, cache, *counts = models.decode_step(
+            cfg, params, cache, toks[:, :, 0], **moe)
         nxt = jax.vmap(_pick)(logits[:, -1], keys, steps)
         nxt = jnp.where(active, nxt, 0)
         row = jnp.arange(out_buf.shape[0])
@@ -215,7 +220,7 @@ def _programs(cfg, temp: float, top_k_n: int):
             jnp.where(active, nxt, out_buf[row, idx])
         )
         steps = steps + active.astype(jnp.int32)
-        return cache, nxt[:, None, None], out_buf, steps, keys
+        return (cache, nxt[:, None, None], out_buf, steps, keys, *counts)
 
     def _admit(cache, toks, out_buf, steps, keys, row_cache, tok0,
                row_key, idx):
@@ -291,6 +296,9 @@ class SlotScheduler:
         self.stream = stream
         self._base_key = jax.random.PRNGKey(sampling_seed)
         self._state = None           # (cache, toks, out_buf, steps, keys)
+        # the expert layers' counters of the last step, read at its token
+        # sync (streaming only; None where not read or the model has none)
+        self.step_counts: dict | None = None
         self.active = np.zeros(max_batch, dtype=bool)
         self.slot_meta: list[_Slot | None] = [None] * max_batch
 
@@ -337,13 +345,16 @@ class SlotScheduler:
 
         Returns the slot index. The prefill is the engine's own jitted
         closure, so requests with equal prompt lengths share one compiled
-        prefill program."""
+        prefill program. A streaming loop reads the prefill's expert
+        counters at its first-token sync, onto the ``serve.admit.prefill``
+        span."""
         free = self.free_slots
         if not free:
             raise RuntimeError("admit called with no free slot")
         idx = free[0]
         eng = self.engine
-        with spans.span("serve.admit.prefill"):
+        with spans.span("serve.admit.prefill",
+                        prompt_len=len(req.prompt)) as prefill_span:
             batch = {"tokens": jnp.asarray(req.prompt, jnp.int32)[None]}
             if eng.cfg.is_encdec:
                 rng = np.random.default_rng(0)
@@ -353,7 +364,7 @@ class SlotScheduler:
                     ),
                     jnp.dtype(eng.cfg.dtype),
                 )
-            logits, row_cache = eng._prefill(eng.params, batch)
+            logits, row_cache, *counts = eng._prefill(eng.params, batch)
         with spans.span("serve.admit.sample"):
             row_key = self._request_key(req.rid)
             tok0 = self._pick(logits[0, -1], row_key, 0)
@@ -381,6 +392,8 @@ class SlotScheduler:
             # step) so the prefill token can ride the first PARTIAL frame
             with spans.span("serve.admit.sync"):
                 meta.first_token = int(tok0)
+                if counts:
+                    prefill_span.annotate(**_read_counts(counts[0]))
         self.slot_meta[idx] = meta
         return idx
 
@@ -390,18 +403,23 @@ class SlotScheduler:
         Returns the per-slot token deltas when streaming (one host sync
         of the (slots,) next-token feed — the per-token cost streaming
         inherently pays), else None (no sync; tokens stay device-side
-        until ``pop_finished``)."""
+        until ``pop_finished``). At that sync a model with an expert layer
+        also reads the step's counters into ``step_counts``, which the
+        loop puts on the ``serve.step`` span."""
         cache, toks, out_buf, steps, keys = self._state
         with spans.span("serve.step.dispatch"):
-            cache, toks, out_buf, steps, keys = self._step_fn(
+            cache, toks, out_buf, steps, keys, *counts = self._step_fn(
                 self.engine.params, cache, toks, out_buf, steps, keys,
                 jnp.asarray(self.active),
             )
         self._state = (cache, toks, out_buf, steps, keys)
         deltas: list[TokenDelta] | None = None
+        self.step_counts = None
         if self.stream:
             with spans.span("serve.step.sync"):
                 feed = np.asarray(toks)      # (slots, 1, 1): just-sampled
+                if counts:
+                    self.step_counts = _read_counts(counts[0])
             deltas = [
                 TokenDelta(
                     rid=meta.request.rid,
@@ -687,9 +705,11 @@ def run_serve_loop(
         # 3) advance every active slot one token
         n_active = sched.n_active
         if n_active:
-            with spans.span("serve.step", n_active=n_active):
+            with spans.span("serve.step", n_active=n_active) as step_span:
                 faults.on_decode_step(report.steps + 1)
                 deltas = sched.step()
+                if sched.step_counts:
+                    step_span.annotate(**sched.step_counts)
                 report.steps += 1
                 if on_delta is not None and deltas:
                     with spans.span("serve.step.emit"):
@@ -713,6 +733,11 @@ def run_serve_loop(
     report.wall_s = time.monotonic() - t0
     report.programs_lowered = _lowered_since(t0_ns)
     return report
+
+
+def _read_counts(counts) -> dict:
+    """The expert layers' counters, fetched from the device."""
+    return dict(zip(models.moe.COUNTERS, np.asarray(counts).tolist()))
 
 
 def _lowered_since(t0_ns: int) -> dict:

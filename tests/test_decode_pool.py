@@ -68,8 +68,6 @@ def _row(pool, b):
 @pytest.mark.parametrize("family", sorted(FAMILIES))
 def test_pool_step_matches_each_row_alone(family):
     cfg = get_config(FAMILIES[family], smoke=True)
-    if cfg.is_moe:
-        cfg = cfg.replace(capacity_factor=float(cfg.num_experts))
     params = models.init_params(cfg, 0)
     rows, toks = _rows(cfg, params, np.random.default_rng(7))
     step = jax.jit(lambda p, c, t: models.decode_step(cfg, p, c, t))
@@ -112,7 +110,10 @@ def test_unrolled_pool_step_matches_the_scan(family):
 # The tokens a sampled serve loop with admissions mid-flight produced when
 # the scheduler stepped a vmap of B=1 decode steps over a slot-major pool
 # (rids 0..4; temperature 0.7, top-k 8, sampling seed 5). Stepping the pool
-# in place in the family's own layout must not change one of them.
+# in place in the family's own layout must not change one of them. The moe
+# row was computed again one request at a time (prefill, then decode steps
+# at B=1) when the expert layer became dropless and olmoe took its
+# published routing (no renormalisation) and full-width q/k norms.
 SERVED = {
     "dense": [[193, 125, 18, 200, 89, 202], [181, 131, 207],
               [44, 99, 202, 27, 18], [191, 124, 207, 120],
@@ -120,8 +121,9 @@ SERVED = {
     "windowed": [[77, 78, 79, 223, 231, 197], [231, 191, 240],
                  [70, 94, 124, 42, 223], [70, 77, 70, 104],
                  [16, 223, 223, 63, 227, 215]],
-    "moe": [[0, 7, 36, 89, 89, 42], [89, 115, 174], [187, 250, 219, 187, 187],
-            [240, 168, 100, 168], [238, 36, 247, 16, 235, 36]],
+    "moe": [[89, 42, 234, 103, 231, 137], [89, 116, 187],
+            [187, 52, 38, 204, 99], [240, 168, 207, 236],
+            [190, 247, 190, 37, 105, 40]],
     "mamba2": [[41, 3, 104, 6, 80, 48], [67, 196, 53], [5, 142, 124, 13, 32],
                [98, 252, 44, 196], [61, 85, 47, 179, 98, 2]],
     "hybrid": [[136, 233, 87, 223, 12, 183], [168, 23, 114],

@@ -46,6 +46,24 @@ def test_spans_nest_by_thread_and_carry_their_attributes():
     assert outer.attrs == {"rid": 7} and inner.attrs is None
 
 
+def test_annotate_adds_attributes_while_open_and_after_close():
+    """A counter read at a later sync reaches the record of a span opened
+    with attributes; one opened bare takes attributes only while open."""
+    t = time.monotonic_ns()
+    with spans.span("t.open") as sp:
+        sp.annotate(rows=3)
+    with spans.span("t.late", prompt_len=5) as late:
+        pass
+    late.annotate(rows=7)
+    with spans.span("t.bare") as bare:
+        pass
+    bare.annotate(rows=9)
+    got = {r.name: r for r in _since(t)}
+    assert got["t.open"].attrs == {"rows": 3}
+    assert got["t.late"].attrs == {"prompt_len": 5, "rows": 7}
+    assert got["t.bare"].attrs is None
+
+
 def test_the_ring_keeps_the_newest_records():
     rec = spans.Recorder(size=8)
     for i in range(20):

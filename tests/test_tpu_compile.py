@@ -68,11 +68,33 @@ def test_mamba2_prefill_compiles_for_v5e(one_chip, engine):
     _fits(compiled)
 
 
-# (arch, slots, cache_len) of the benchmark's two cells
+# (arch, slots, cache_len) of the benchmark's cells
 SLOT_POOLS = {
     "mamba2-370m": (64, 512 + 16),
     "starcoder2-3b": (32, 4096),
+    "olmoe-1b-7b": (48, 1536),
 }
+
+
+def _served(arch):
+    """The configuration a cell serves: olmoe as one chip's share under
+    expert parallelism 4, 16 of its 64 experts held."""
+    cfg = get_config(arch)
+    return cfg.replace(experts_held=16) if cfg.is_moe else cfg
+
+
+def test_olmoe_prefill_compiles_for_v5e(one_chip):
+    """The longest prompt of the chat mix through the expert layer's
+    grouped-matmul kernel, beside the weights of 16 held experts."""
+    from repro.serve import ServeEngine
+
+    cfg = _served("olmoe-1b-7b")
+    engine = ServeEngine(cfg, params=None, cache_len=1536)
+    params = _on(one_chip, models.abstract(cfg))
+    batch = _on(one_chip, {"tokens": jax.ShapeDtypeStruct((1, 1024), jnp.int32)})
+    compiled = engine._prefill.lower(params, batch).compile()
+    _fits(compiled)
+    assert "moe_gmm" in compiled.as_text()
 
 
 @pytest.mark.parametrize("arch", sorted(SLOT_POOLS))
@@ -86,7 +108,7 @@ def test_slot_decode_step_compiles_for_v5e(one_chip, arch):
     from repro.serve.scheduler import SlotScheduler
 
     slots, cache_len = SLOT_POOLS[arch]
-    cfg = get_config(arch)
+    cfg = _served(arch)
     engine = ServeEngine(cfg, params=None, cache_len=cache_len)
     sched = SlotScheduler(engine, max_batch=slots)
     pool, _ = models.cache_spec(cfg, slots, cache_len)
@@ -115,6 +137,7 @@ def test_slot_decode_step_compiles_for_v5e(one_chip, arch):
 
 def _kernel_cases():
     from repro.kernels.flash_attention import flash_attention_bhsd
+    from repro.kernels.moe_gmm import moe_gmm
     from repro.kernels.paged_reloc_copy import paged_reloc_copy
     from repro.kernels.rmsnorm import rmsnorm_2d
 
@@ -128,6 +151,12 @@ def _kernel_cases():
             sds((1, 2, 2048, 128), bf16),
         )),
         "rmsnorm": (rmsnorm_2d, (sds((4096, 1024), bf16), sds((1024,), bf16))),
+        # olmoe's down projection over 16 held experts, 48 slots x top-8
+        "moe_gmm": (moe_gmm, (
+            sds((384, 1024), bf16),
+            sds((16, 1024, 2048), bf16),
+            sds((16,), i32),
+        )),
         "paged_reloc_copy": (paged_reloc_copy, (
             sds((4096, 8, 128), i32),
             sds((4096, 8, 128), i32),
@@ -137,7 +166,8 @@ def _kernel_cases():
     }
 
 
-@pytest.mark.parametrize("kernel", ["flash_attention", "rmsnorm", "paged_reloc_copy"])
+@pytest.mark.parametrize("kernel", ["flash_attention", "rmsnorm", "paged_reloc_copy",
+                                    "moe_gmm"])
 def test_kernel_lowers_to_tpu_custom_call(one_chip, kernel):
     fn, shapes = _kernel_cases()[kernel]
     compiled = fn.lower(*_on(one_chip, shapes)).compile()
