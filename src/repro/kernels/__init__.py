@@ -9,12 +9,22 @@
     moe_gmm          — grouped matmul over a chip's held experts, rows
                        sorted by expert, group sizes from the routing
                        (the expert layer's dropless SwiGLU)
+    decode_attention — one token per row against one layer of the stacked
+                       slot pool, read in place, each row's blocks only up
+                       to its length (the decode step's attention)
 
 Each package: <name>.py (pl.pallas_call + BlockSpec), ops.py (jit wrapper),
 ref.py (pure-jnp oracle). Validated on CPU with interpret=True; compiled
 via Mosaic on TPU.
 """
 
-from . import flash_attention, moe_gmm, paged_reloc_copy, rmsnorm
+from . import (
+    decode_attention,
+    flash_attention,
+    moe_gmm,
+    paged_reloc_copy,
+    rmsnorm,
+)
 
-__all__ = ["flash_attention", "moe_gmm", "paged_reloc_copy", "rmsnorm"]
+__all__ = ["decode_attention", "flash_attention", "moe_gmm",
+           "paged_reloc_copy", "rmsnorm"]

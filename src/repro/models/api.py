@@ -22,6 +22,7 @@ import numpy as np
 from repro.core import SymbolRef
 
 from . import hybrid, mamba2, transformer
+from .common import FREE_POS
 from .specs import ParamSpec, abstract_params, init_params as _init
 from .specs import init_params_np as _init_np
 from .specs import param_bytes, param_count
@@ -65,8 +66,18 @@ def prefill(cfg, params, batch, *, impl="chunked", cache_len=None, **moe):
 
 def decode_step(cfg, params, cache, tokens, **moe):
     """(logits, cache); a model with routed experts also takes ``active``
-    (B,), the rows its expert layers route, and ``counters``."""
+    (B,), the rows its expert layers route, and ``counters``. A row whose
+    ``pos`` is ``FREE_POS`` holds no token: its attention reads no cache."""
     return _mod(cfg).decode_step(cfg, params, cache, tokens, **moe)
+
+
+def decode_attention_layers(cfg, cache) -> int:
+    """How many layers of the decode step read ``cache`` through the
+    decode-attention kernel: the transformer family's full-attention
+    layers; none in any other family."""
+    if _mod(cfg) is not transformer:
+        return 0
+    return transformer.kernel_layers(cfg, cache["k"].shape[2])
 
 
 def cache_spec(cfg, batch, seq_len):

@@ -19,6 +19,9 @@ import jax.numpy as jnp
 import numpy as np
 
 NEG_INF = -2.0**30  # large-but-finite: keeps masked softmax NaN-free
+# a decode cache row's ``pos`` when the row holds no token (a free slot):
+# its attention reads no cache and comes out as zeros
+FREE_POS = -1
 
 
 # ------------------------------------------------------------------- norms
@@ -187,42 +190,6 @@ def attention(
             interpret=impl == "pallas_interpret",
         )
     raise ValueError(f"unknown attention impl {impl!r}")
-
-
-def decode_attention(
-    q: jax.Array,          # (B, 1, H, hd)
-    k_cache: jax.Array,    # (B, S, KV, hd)
-    v_cache: jax.Array,
-    pos: jax.Array,        # int32, () or (B,): index of the *current* token
-) -> jax.Array:
-    """Single-token attention against a cache; entries beyond pos masked
-    (``pos`` one for all rows, or one per row).
-
-    GQA is computed as a grouped einsum against the UNEXPANDED cache —
-    ``repeat_kv`` here would materialize (and, under SPMD, all-gather +
-    f32-upcast) a head-expanded copy of the whole cache; the grouped form
-    keeps the cache bf16 and sharded (§Perf hillclimb B: 2.04e11 ->
-    ~0 collective bytes/step on deepseek-67b decode_32k).
-
-    Sequence-sharded caches (LONG_CONTEXT_RULES) stay correct: the softmax
-    reduction over the sharded S axis becomes a cross-device partial-max/sum
-    combine under GSPMD (flash-decode).
-    """
-    b, s, kvh, hd = k_cache.shape
-    h = q.shape[2]
-    g = h // kvh
-    qg = q.reshape(b, 1, kvh, g, hd)
-    scores = jnp.einsum(
-        "bqkgd,bskd->bkgqs", qg, k_cache,
-        preferred_element_type=jnp.float32,
-    ) * hd**-0.5                                         # (B,KV,G,1,S) f32
-    valid = jnp.arange(s) <= jnp.reshape(pos, (-1, 1, 1, 1, 1))
-    scores = jnp.where(valid, scores, NEG_INF)
-    w = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum(
-        "bkgqs,bskd->bqkgd", w.astype(v_cache.dtype), v_cache
-    )
-    return out.reshape(b, 1, h, hd)
 
 
 # --------------------------------------------------------------------- MLP

@@ -17,14 +17,15 @@ import jax
 import jax.numpy as jnp
 
 from .common import (
+    FREE_POS,
     apply_rope,
     attention,
     cross_entropy,
-    decode_attention,
     mlp,
     rms_norm,
     rope_angles,
 )
+from repro.kernels.decode_attention import decode_attention_ref
 from . import mamba2
 from .runtime import remat_wrap, scans_unrolled
 from .specs import ParamSpec
@@ -206,13 +207,15 @@ def decode_step(cfg, params, cache, tokens):
     scalar or one position per row (B,). Every cache is written in place:
     each attention invocation its new token's K and V at each row's
     ``pos % S``, each mamba layer its conv state, and the SSM states all
-    at once after the last layer (``mamba2.decode_layers``)."""
+    at once after the last layer (``mamba2.decode_layers``). A row whose
+    ``pos`` is ``FREE_POS`` (a free slot) reads no cache."""
     B = tokens.shape[0]
     hd = _hd(cfg)
     pos = cache["pos"] + 1
     rpos = jnp.broadcast_to(pos, (B,))
     rows = jnp.arange(B)
     S = cache["k"].shape[2]
+    lengths = jnp.where(cache["pos"] == FREE_POS, 0, rpos + 1)
     x = jnp.take(params["embed/tokens"], tokens, axis=0)
     x0 = x
     sin, cos = rope_angles(rpos[:, None], hd, cfg.rope_theta)
@@ -230,7 +233,7 @@ def decode_step(cfg, params, cache, tokens):
         k_new = apply_rope(k_new, sin, cos)
         k_all = k_all.at[i, rows, rpos % S].set(k_new[:, 0])
         v_all = v_all.at[i, rows, rpos % S].set(v_new[:, 0])
-        o = decode_attention(q, k_all[i], v_all[i], rpos)
+        o = decode_attention_ref(q, k_all[i], v_all[i], lengths)
         a = o.reshape(B, 1, -1) @ params["shared_attn/wo"]
         hm = rms_norm(a, params["shared_attn/mlp_norm/scale"], cfg.norm_eps)
         a = a + mlp(

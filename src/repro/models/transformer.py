@@ -18,10 +18,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from .common import (
+    FREE_POS,
     apply_rope,
     attention,
     cross_entropy,
-    decode_attention,
     layer_norm,
     mlp,
     repeat_kv,
@@ -29,6 +29,10 @@ from .common import (
     rope_angles,
 )
 from repro.dist.context import constrain
+from repro.kernels.decode_attention import (
+    decode_attention,
+    decode_attention_ref,
+)
 from .moe import moe_block, no_counts, shared_expert
 from .runtime import layer_loop, remat_wrap, scans_unrolled
 from .specs import ParamSpec
@@ -433,6 +437,13 @@ def cache_spec(cfg, batch: int, seq_len: int):
     return shapes, axes
 
 
+def kernel_layers(cfg, seq_len: int) -> int:
+    """Self-attention layers whose decode step reads a cache of
+    ``seq_len`` positions through the decode-attention kernel: all but
+    those with a window shorter than the cache."""
+    return sum(not (w and w < seq_len) for w in _layer_windows(cfg))
+
+
 def init_cache(cfg, batch: int, seq_len: int):
     shapes, _ = cache_spec(cfg, batch, seq_len)
     return {k: jnp.zeros(s.shape, s.dtype) for k, s in shapes.items()}
@@ -492,9 +503,14 @@ def decode_step(cfg, params, cache, tokens, *, active=None,
 
     ``pos`` is a scalar or one position per row (B,). The stacked K/V
     caches are carried through the layer loop: each layer writes its new
-    token's K and V at each row's ``pos % S`` in place and reads its own
-    slice back. ``active`` (B,) bool marks the rows an expert layer routes:
-    a free slot's row costs its experts nothing (None: every row)."""
+    token's K and V at each row's ``pos % S`` in place, and a full-attention
+    layer hands the whole stacked cache and its own index to the
+    decode-attention kernel, which reads each row's positions up to
+    ``min(pos + 1, S)`` where the cache lies (windowed layers read their
+    window through XLA). A row whose ``pos`` is ``FREE_POS`` (a free slot)
+    reads no cache. ``active`` (B,) bool marks the rows an expert layer
+    routes: a free slot's row costs its experts nothing (None: every
+    row)."""
     B = tokens.shape[0]
     hd = cfg.resolved_head_dim
     pos = cache["pos"] + 1  # position being written
@@ -507,6 +523,7 @@ def decode_step(cfg, params, cache, tokens, *, active=None,
     windows = _layer_windows(cfg)
     homogeneous = len(set(windows)) == 1
     S = cache["k"].shape[2]
+    lengths = jnp.where(cache["pos"] == FREE_POS, 0, jnp.minimum(rpos + 1, S))
 
     def layer(carry, p, i, window, xk=None, xv=None):
         x, k_all, v_all = carry
@@ -516,17 +533,18 @@ def decode_step(cfg, params, cache, tokens, *, active=None,
         k_new = apply_rope(k_new, sin, cos)
         k_all = k_all.at[i, rows, rpos % S].set(k_new[:, 0])
         v_all = v_all.at[i, rows, rpos % S].set(v_new[:, 0])
-        k_c = jax.lax.dynamic_index_in_dim(k_all, i, 0, keepdims=False)
-        v_c = jax.lax.dynamic_index_in_dim(v_all, i, 0, keepdims=False)
         if window and window < S:
             start = jnp.clip(rpos - window + 1, 0, S - window)
             take = jax.vmap(
                 lambda c, s0: jax.lax.dynamic_slice_in_dim(c, s0, window, 0)
             )
-            o = decode_attention(q, take(k_c, start), take(v_c, start),
-                                 rpos - start)
+            k_c = jax.lax.dynamic_index_in_dim(k_all, i, 0, keepdims=False)
+            v_c = jax.lax.dynamic_index_in_dim(v_all, i, 0, keepdims=False)
+            o = decode_attention_ref(q, take(k_c, start), take(v_c, start),
+                                     rpos - start + 1)
         else:
-            o = decode_attention(q, k_c, v_c, rpos)
+            o = decode_attention(q, k_all, v_all, jnp.asarray(i, jnp.int32),
+                                 lengths)
         o = o.reshape(B, 1, -1) @ p["attn/wo"]
         if cfg.use_bias:
             o = o + p["attn/bo"]
@@ -534,7 +552,7 @@ def decode_step(cfg, params, cache, tokens, *, active=None,
         if xk is not None:
             h = _norm(p, "xattn_norm", x, cfg)
             q2 = (h @ p["xattn/wq"]).reshape(B, 1, cfg.num_heads, hd)
-            o = decode_attention(q2, xk, xv, jnp.int32(xk.shape[1] - 1))
+            o = decode_attention_ref(q2, xk, xv, jnp.int32(xk.shape[1]))
             x = x + o.reshape(B, 1, -1) @ p["xattn/wo"]
         h = _norm(p, "mlp_norm", x, cfg)
         m, _, counts = _mlp_or_moe(cfg, p, h, rows=active)

@@ -46,6 +46,7 @@ import numpy as np
 from repro import models
 from repro.core import spans
 from repro.core.errors import EpochAdoptError
+from repro.kernels import decode_attention
 
 from . import faults
 
@@ -207,8 +208,12 @@ def _programs(cfg, temp: float, top_k_n: int):
         ).astype(jnp.int32)
 
     def _step(params, cache, toks, out_buf, steps, keys, active):
-        # a model with an expert layer also returns its counts; a free
-        # slot's row costs the experts nothing
+        # a free slot's row reads no cache (its pos is -1, models.FREE_POS)
+        # and costs the experts nothing; a model with an expert layer also
+        # returns its counts
+        if cfg.family != "ssm":
+            cache = dict(cache, pos=jnp.where(active, cache["pos"],
+                                              models.FREE_POS))
         moe = dict(active=active, counters=True) if cfg.is_moe else {}
         logits, cache, *counts = models.decode_step(
             cfg, params, cache, toks[:, :, 0], **moe)
@@ -296,9 +301,14 @@ class SlotScheduler:
         self.stream = stream
         self._base_key = jax.random.PRNGKey(sampling_seed)
         self._state = None           # (cache, toks, out_buf, steps, keys)
-        # the expert layers' counters of the last step, read at its token
-        # sync (streaming only; None where not read or the model has none)
+        # the last step's counters: the decode-attention kernel's reads,
+        # worked out here from the rows' positions, and the expert layers'
+        # counts, read at its token sync (streaming only); None where the
+        # model has neither
         self.step_counts: dict | None = None
+        # (layers the kernel reads, positions per row, per block), set with
+        # the pool; None for a model whose step runs no such kernel
+        self._attn: tuple[int, int, int] | None = None
         self.active = np.zeros(max_batch, dtype=bool)
         self.slot_meta: list[_Slot | None] = [None] * max_batch
 
@@ -330,6 +340,10 @@ class SlotScheduler:
             lambda r: jnp.zeros(_pool_shape(np.shape(r), self.slots), r.dtype),
             row_cache,
         )
+        layers = models.decode_attention_layers(self.engine.cfg, cache)
+        if layers:
+            k = cache["k"]
+            self._attn = (layers, k.shape[2], decode_attention.block_of(k))
         self._state = (
             cache,
             jnp.zeros((self.slots, 1, 1), jnp.int32),
@@ -407,6 +421,7 @@ class SlotScheduler:
         also reads the step's counters into ``step_counts``, which the
         loop puts on the ``serve.step`` span."""
         cache, toks, out_buf, steps, keys = self._state
+        self.step_counts = self._attn_counts()
         with spans.span("serve.step.dispatch"):
             cache, toks, out_buf, steps, keys, *counts = self._step_fn(
                 self.engine.params, cache, toks, out_buf, steps, keys,
@@ -414,12 +429,12 @@ class SlotScheduler:
             )
         self._state = (cache, toks, out_buf, steps, keys)
         deltas: list[TokenDelta] | None = None
-        self.step_counts = None
         if self.stream:
             with spans.span("serve.step.sync"):
                 feed = np.asarray(toks)      # (slots, 1, 1): just-sampled
                 if counts:
-                    self.step_counts = _read_counts(counts[0])
+                    self.step_counts = {**(self.step_counts or {}),
+                                        **_read_counts(counts[0])}
             deltas = [
                 TokenDelta(
                     rid=meta.request.rid,
@@ -433,6 +448,23 @@ class SlotScheduler:
             if meta is not None:
                 meta.steps_done += 1
         return deltas
+
+    def _attn_counts(self) -> dict | None:
+        """The decode-attention kernel's reads in the next step, from the
+        active rows' positions: ``attn_kv_read``, the positions it is
+        handed (each row's ``min(pos + 1, S)``, rounded up to its block),
+        and ``attn_kv_held``, the positions the pool holds, both summed
+        over the layers it runs in."""
+        if self._attn is None:
+            return None
+        layers, seq_len, block = self._attn
+        read = sum(
+            -(-min(m.request.prompt.shape[0] + m.steps_done, seq_len)
+              // block) * block
+            for m in self.slot_meta if m is not None
+        )
+        return {"attn_kv_read": layers * read,
+                "attn_kv_held": layers * self.slots * seq_len}
 
     def pop_finished(self, now: float) -> list[Completion]:
         """Retire every slot whose host-mirrored step count hit its target.

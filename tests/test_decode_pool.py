@@ -168,3 +168,51 @@ def test_midflight_admissions_serve_the_same_tokens(family):
     )
     assert report.completed == 5 and report.peak_active <= 3
     assert [done[i].tokens.tolist() for i in range(5)] == SERVED[family]
+
+
+# (architecture, KV heads) of a tiny grouped-query and a tiny multi-head
+# model, and the tokens the serve loop below gave for them when attention
+# read the whole pool through XLA
+COUNTED = {
+    "gqa": ("starcoder2-3b", 2, [[83, 64, 83, 186], [12, 253, 253, 18]]),
+    "mha": ("olmoe-1b-7b", 4, [[209, 238, 182, 54], [27, 158, 201, 122]]),
+}
+
+
+@pytest.mark.parametrize("heads", sorted(COUNTED))
+def test_step_spans_count_the_kernels_reads(heads, monkeypatch):
+    """Two requests in three slots (one free), prompts of 127 and 5 tokens
+    in a pool of 256 positions read in blocks of 128: each ``serve.step``
+    span carries the positions the decode-attention kernel is handed,
+    rounded up to its block, and those the pool holds, summed over layers;
+    the tokens are those of the XLA path."""
+    import importlib
+
+    from repro.core import spans
+    from repro.serve import STOP, Request, ServeEngine
+
+    arch, kv, tokens = COUNTED[heads]
+    cfg = get_config(arch, smoke=True).replace(num_kv_heads=kv)
+    width = kv * cfg.resolved_head_dim * np.dtype(cfg.dtype).itemsize
+    kernel = importlib.import_module(
+        "repro.kernels.decode_attention.decode_attention")
+    monkeypatch.setattr(kernel, "BLOCK_BYTES", 128 * width)
+    engine = ServeEngine(cfg, models.init_params(cfg, 0), cache_len=256)
+    rng = np.random.default_rng(3)
+    reqs = [Request(rid=i, max_new_tokens=4, prompt=rng.integers(
+        0, cfg.vocab_size, n, dtype=np.int32)) for i, n in enumerate((127, 5))]
+    src = iter(reqs + [STOP])
+    done = {}
+    seen = max((r.index for r in spans.records()), default=-1)
+    engine.serve_loop(lambda: next(src), lambda c: done.setdefault(c.rid, c),
+                      max_batch=3, max_queue=4, on_delta=lambda d: None)
+
+    steps = [r.attrs for r in spans.records()
+             if r.name == "serve.step" and r.index > seen]
+    L = cfg.num_layers
+    # step k reads positions 0 .. 127 + k and 0 .. 5 + k of each layer:
+    # (128, 6), (129, 7), (130, 8), rounded up to blocks of 128
+    assert [s["attn_kv_read"] for s in steps] == [
+        L * (128 + 128), L * (256 + 128), L * (256 + 128)]
+    assert [s["attn_kv_held"] for s in steps] == [L * 3 * 256] * 3
+    assert [done[i].tokens.tolist() for i in range(2)] == tokens

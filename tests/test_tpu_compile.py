@@ -13,6 +13,10 @@ worker loads the library.
 
 from __future__ import annotations
 
+import functools
+import hashlib
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -97,13 +101,31 @@ def test_olmoe_prefill_compiles_for_v5e(one_chip):
     assert "moe_gmm" in compiled.as_text()
 
 
+def _op_list(hlo: str) -> list[str]:
+    """Every instruction of a compiled module as its opcode and result type
+    (shape and layout), sorted: what it computes, without names or source
+    lines."""
+    ops = re.findall(r"^\s*(?:ROOT )?%[\w.\-]+ = (.+?) ([\w\-]+)\(", hlo,
+                     re.MULTILINE)
+    return sorted(f"{op} {ty}" for ty, op in ops)
+
+
+# mamba2's step runs no attention: the decode-attention kernel must leave
+# its program as it was. The digest of its op list (``_op_list``) compiled
+# before the kernel existed, 663 instructions.
+MAMBA2_STEP_OPS = "4861920a49d600c0"
+
+
 @pytest.mark.parametrize("arch", sorted(SLOT_POOLS))
 def test_slot_decode_step_compiles_for_v5e(one_chip, arch):
     """The served decode program: SlotScheduler's step over the slot pool,
     the family's cache at batch = slots (every array leaf ``(L, slots,
     ...)``, ``pos`` one per slot). The step updates the donated pool in
     place: its scratch stays under one layer's slice of the pool, and the
-    pool is aliased from input to output, so nothing copies it whole."""
+    pool is aliased from input to output, so nothing copies it whole. An
+    attention model's step hands the stacked K and V to the
+    decode-attention kernel, so no instruction holds one layer's slice of
+    them; mamba2's step is the one it was."""
     from repro.serve import ServeEngine
     from repro.serve.scheduler import SlotScheduler
 
@@ -133,9 +155,23 @@ def test_slot_decode_step_compiles_for_v5e(one_chip, arch):
                                                    layer_bytes)
     assert mem.alias_size_in_bytes >= pool_bytes, (mem.alias_size_in_bytes,
                                                    pool_bytes)
+    hlo = compiled.as_text()
+    if "k" not in pool:
+        ops = _op_list(hlo)
+        digest = hashlib.sha256("\n".join(ops).encode()).hexdigest()[:16]
+        assert digest == MAMBA2_STEP_OPS, (len(ops), digest)
+        return
+    _, _, seq_len, kv, hd = pool["k"].shape
+    dims = f"{slots},{seq_len},{kv},{hd}"
+    assert not re.search(rf"= \S+\[(1,)?{dims}\]", hlo), dims
+    assert "decode_attention" in hlo and "tpu_custom_call" in hlo
 
 
 def _kernel_cases():
+    from repro.kernels.decode_attention import (
+        block_len,
+        decode_attention_kernel,
+    )
     from repro.kernels.flash_attention import flash_attention_bhsd
     from repro.kernels.moe_gmm import moe_gmm
     from repro.kernels.paged_reloc_copy import paged_reloc_copy
@@ -150,6 +186,17 @@ def _kernel_cases():
             sds((1, 2, 2048, 128), bf16),
             sds((1, 2, 2048, 128), bf16),
         )),
+        # starcoder2-3b's slot pool: 32 rows of 4096 positions, 24 query
+        # heads over 2 KV heads, one of 30 layers read
+        "decode_attention": (
+            jax.jit(functools.partial(decode_attention_kernel,
+                                      block=block_len(4096, 256, 2))), (
+                sds((32, 24, 128), bf16),
+                sds((30, 32, 4096, 2, 128), bf16),
+                sds((30, 32, 4096, 2, 128), bf16),
+                sds((), i32),
+                sds((32,), i32),
+            )),
         "rmsnorm": (rmsnorm_2d, (sds((4096, 1024), bf16), sds((1024,), bf16))),
         # olmoe's down projection over 16 held experts, 48 slots x top-8
         "moe_gmm": (moe_gmm, (
@@ -167,7 +214,7 @@ def _kernel_cases():
 
 
 @pytest.mark.parametrize("kernel", ["flash_attention", "rmsnorm", "paged_reloc_copy",
-                                    "moe_gmm"])
+                                    "moe_gmm", "decode_attention"])
 def test_kernel_lowers_to_tpu_custom_call(one_chip, kernel):
     fn, shapes = _kernel_cases()[kernel]
     compiled = fn.lower(*_on(one_chip, shapes)).compile()
