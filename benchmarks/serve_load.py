@@ -34,12 +34,6 @@ emits:
     serve/rollover_stall         us row: wall time from commit to the
                                  whole fleet serving the new generation
 
-It also pins PR 6's satellite fix with a before/after pair on the same
-engine: ``serve/generate_hostsync`` times the OLD decode loop (a blocking
-``np.asarray`` per token — one host<->device round-trip per step) against
-``serve/generate_devacc`` (device-side accumulation, one transfer at the
-end), reported as us per decoded token.
-
 ``--chaos`` is PR 8's hardening measurement, two halves:
 
 * **kill-a-worker tail** — a supervised fleet (``supervise=True``) serves
@@ -99,36 +93,6 @@ def _image_digest(image) -> str:
     for name in sorted(tensors):
         h.update(np.ascontiguousarray(tensors[name]).view(np.uint8).tobytes())
     return h.hexdigest()
-
-
-def _bench_generate_sync_fix(cfg, ws, app_name, *, max_new: int) -> None:
-    """Satellite: the per-step host sync, before vs after, same engine."""
-    from repro.serve import ServeEngine
-
-    from .common import emit
-
-    engine = ServeEngine.from_workspace(
-        cfg, ws, app_name, cache_len=16 + max_new
-    )
-    rng = np.random.default_rng(0)
-    prompts = rng.integers(0, cfg.vocab_size, (4, 16), dtype=np.int32)
-    # warm both code paths (jit compile off the clock), then measure
-    engine.generate(prompts, max_new, host_sync=True)
-    engine.generate(prompts, max_new, host_sync=False)
-    _, before = engine.generate(prompts, max_new, host_sync=True)
-    out_after, after = engine.generate(prompts, max_new, host_sync=False)
-    out_check, _ = engine.generate(prompts, max_new, host_sync=True)
-    np.testing.assert_array_equal(out_after, out_check)
-    emit(
-        "serve/generate_hostsync",
-        before.decode_s / max(before.tokens_out, 1),
-        f"per_token;np.asarray each step;tok_s={before.tok_per_s:.0f}",
-    )
-    emit(
-        "serve/generate_devacc",
-        after.decode_s / max(after.tokens_out, 1),
-        f"per_token;device accumulate;tok_s={after.tok_per_s:.0f}",
-    )
 
 
 def run(
@@ -232,8 +196,6 @@ def run(
         if rollover:
             _check_rollover(ws, app_name, rep, workers=workers,
                             pre_roll_segments=pre_roll_segments)
-
-        _bench_generate_sync_fix(cfg, ws, app_name, max_new=max_new_tokens)
     finally:
         from .common import write_bench_json
 

@@ -71,15 +71,6 @@ def decode_step(cfg, params, cache, tokens, **moe):
     return _mod(cfg).decode_step(cfg, params, cache, tokens, **moe)
 
 
-def decode_attention_layers(cfg, cache) -> int:
-    """How many layers of the decode step read ``cache`` through the
-    decode-attention kernel: the transformer family's full-attention
-    layers; none in any other family."""
-    if _mod(cfg) is not transformer:
-        return 0
-    return transformer.kernel_layers(cfg, cache["k"].shape[2])
-
-
 def cache_spec(cfg, batch, seq_len):
     return _mod(cfg).cache_spec(cfg, batch, seq_len)
 

@@ -123,11 +123,10 @@ class ServeEngine:
 
         def _prefill(params, batch):
             # (logits, cache), and for a model with an expert layer its
-            # counts (models.moe.COUNTERS)
-            moe = dict(counters=True) if cfg.is_moe else {}
+            # counts
             return models.prefill(
-                cfg, params, batch, impl=impl,
-                cache_len=cache_len or None, **moe,
+                cfg, params, batch, impl=impl, cache_len=cache_len or None,
+                **models.pool.prefill_kwargs(cfg),
             )
 
         def _decode(params, cache, tokens):
@@ -356,30 +355,14 @@ class ServeEngine:
         )
 
     def generate(
-        self,
-        prompts: np.ndarray,
-        max_new_tokens: int,
-        *,
-        host_sync: bool = False,
+        self, prompts: np.ndarray, max_new_tokens: int
     ) -> tuple[np.ndarray, ServeStats]:
         """prompts: (B, S) int32 -> (B, max_new_tokens) greedy continuations.
 
         The decode loop accumulates tokens DEVICE-side and pays one host
-        transfer after the final step. ``host_sync=True`` restores the old
-        behaviour (``np.asarray`` per iteration, blocking the host on the
-        device every step) — kept only so ``benchmarks/serve_load.py`` can
-        report the before/after cost of that per-step sync."""
+        transfer after the final step."""
         stats = ServeStats()
-        batch = {"tokens": jnp.asarray(prompts, jnp.int32)}
-        if self.cfg.is_encdec:
-            # modality stub: frames derived deterministically from prompts
-            rng = np.random.default_rng(0)
-            batch["frames"] = jnp.asarray(
-                rng.standard_normal(
-                    (prompts.shape[0], prompts.shape[1], self.cfg.d_model)
-                ),
-                jnp.dtype(self.cfg.dtype),
-            )
+        batch = models.pool.prompt_batch(self.cfg, prompts)
         t0 = time.perf_counter()
         logits, cache = self._prefill(self.params, batch)[:2]
         tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)[:, None]
@@ -388,16 +371,6 @@ class ServeEngine:
 
         out = [tok]
         t1 = time.perf_counter()
-        if host_sync:
-            # legacy path: one blocking device->host round-trip per token
-            host = [np.asarray(tok)]
-            for _ in range(max_new_tokens - 1):
-                tok, cache = self._decode(self.params, cache, tok)
-                host.append(np.asarray(tok))
-            jax.block_until_ready(tok)
-            stats.decode_s = time.perf_counter() - t1
-            stats.tokens_out = prompts.shape[0] * max_new_tokens
-            return np.concatenate(host, axis=1), stats
         for _ in range(max_new_tokens - 1):
             tok, cache = self._decode(self.params, cache, tok)
             out.append(tok)
@@ -442,7 +415,7 @@ class ServeEngine:
         """
         from . import scheduler
 
-        if self.cache_len <= 0 and self.cfg.family not in ("ssm",):
+        if self.cache_len <= 0 and models.pool.attends(self.cfg):
             raise ValueError(
                 "serve_loop needs an engine built with cache_len > "
                 "prompt_len + max_new_tokens (slot K/V rows need decode "

@@ -8,18 +8,13 @@ request's private cache row (KV for transformers, conv/ssm state for
 mamba2/hybrid — the per-request ``InferenceCache`` idiom), admitted and
 retired independently at every decode step.
 
-The trick that keeps this jit-friendly across all three model families:
-every family's decode cache is a pytree whose array leaves carry batch at
-axis 1 (``(L, B, ...)``) beside ``pos``, and every ``decode_step`` takes
-``pos`` as one scalar or one position per row. The pool is that cache at
-batch = ``max_batch`` (``pos`` becomes ``(slots,)``), and one call of
-``models.decode_step`` on the whole pool advances every slot in a single
-compiled dispatch: per-slot positions, RoPE phases and ring-buffer writes
-come from the per-row ``pos``. The step updates the pool in place, in the
-layout it reads, so the donated pool is never copied whole.
-Admission splices a freshly prefilled B=1 cache into its slot at axis 1
-with ``dynamic_update_slice`` (donated, so it is an in-place row write on
-the device buffer).
+The slots' caches are one pool in the family's own cache layout, kept by
+``models.pool``: it builds the pool from a B=1 row, splices a freshly
+prefilled row in at its slot (donated, so it is an in-place row write on
+the device buffer), marks the free rows and steps the whole pool with one
+call of ``models.decode_step`` in a single compiled dispatch, and counts
+what that step reads. This module knows slots, requests and tokens, and
+nothing of how a family keeps its state.
 
 Host/device contract (this is where PR 6's satellite fix generalizes):
 the decode loop never syncs per step. Sampled tokens are scattered into a
@@ -46,7 +41,6 @@ import numpy as np
 from repro import models
 from repro.core import spans
 from repro.core.errors import EpochAdoptError
-from repro.kernels import decode_attention
 
 from . import faults
 
@@ -176,23 +170,11 @@ class _Slot:
     first_token: int = -1            # prefill's token, host-side iff streaming
 
 
-#: Axis of the slot (batch) in every array leaf of a family's decode cache.
-_SLOT_AXIS = 1
-
-
-def _pool_shape(row_shape: tuple, slots: int) -> tuple:
-    """Shape of a pool leaf for ``slots`` rows, from a B=1 cache leaf:
-    the slot at axis 1 of each array, a scalar (``pos``) one per slot."""
-    if not row_shape:
-        return (slots,)
-    return row_shape[:_SLOT_AXIS] + (slots,) + row_shape[_SLOT_AXIS + 1:]
-
-
 def _programs(cfg, temp: float, top_k_n: int):
     """The slot scheduler's sampler and its two jitted programs: ``_step``
-    (advance every slot one token; for a model with an expert layer it
-    also returns the layers' counters, ``models.moe.COUNTERS``) and
-    ``_admit`` (splice one B=1 cache row in)."""
+    (advance every slot one token, and return the expert layers' counts
+    where ``models.pool.free_rows`` asks for them) and ``_admit`` (splice
+    one B=1 cache row in)."""
 
     def _pick(logits, key, pos):
         # greedy vs sampled is a Python-static branch: temperature is
@@ -208,15 +190,9 @@ def _programs(cfg, temp: float, top_k_n: int):
         ).astype(jnp.int32)
 
     def _step(params, cache, toks, out_buf, steps, keys, active):
-        # a free slot's row reads no cache (its pos is -1, models.FREE_POS)
-        # and costs the experts nothing; a model with an expert layer also
-        # returns its counts
-        if cfg.family != "ssm":
-            cache = dict(cache, pos=jnp.where(active, cache["pos"],
-                                              models.FREE_POS))
-        moe = dict(active=active, counters=True) if cfg.is_moe else {}
+        cache, free = models.pool.free_rows(cfg, cache, active)
         logits, cache, *counts = models.decode_step(
-            cfg, params, cache, toks[:, :, 0], **moe)
+            cfg, params, cache, toks[:, :, 0], **free)
         nxt = jax.vmap(_pick)(logits[:, -1], keys, steps)
         nxt = jnp.where(active, nxt, 0)
         row = jnp.arange(out_buf.shape[0])
@@ -229,14 +205,7 @@ def _programs(cfg, temp: float, top_k_n: int):
 
     def _admit(cache, toks, out_buf, steps, keys, row_cache, tok0,
                row_key, idx):
-        cache = jax.tree_util.tree_map(
-            lambda s, r: jax.lax.dynamic_update_slice_in_dim(
-                s, jnp.atleast_1d(r).astype(s.dtype),
-                idx, _SLOT_AXIS if r.ndim else 0,
-            ),
-            cache,
-            row_cache,
-        )
+        cache = models.pool.splice(cache, row_cache, idx)
         zrow = jnp.zeros((1, out_buf.shape[1]), jnp.int32)
         zrow = zrow.at[0, 0].set(tok0)
         out_buf = jax.lax.dynamic_update_slice_in_dim(out_buf, zrow, idx, 0)
@@ -301,14 +270,10 @@ class SlotScheduler:
         self.stream = stream
         self._base_key = jax.random.PRNGKey(sampling_seed)
         self._state = None           # (cache, toks, out_buf, steps, keys)
-        # the last step's counters: the decode-attention kernel's reads,
-        # worked out here from the rows' positions, and the expert layers'
-        # counts, read at its token sync (streaming only); None where the
-        # model has neither
+        # the last step's counters (``models.pool``): the decode-attention
+        # kernel's reads, and the expert layers' counts, read at its token
+        # sync (streaming only); None where the model has neither
         self.step_counts: dict | None = None
-        # (layers the kernel reads, positions per row, per block), set with
-        # the pool; None for a model whose step runs no such kernel
-        self._attn: tuple[int, int, int] | None = None
         self.active = np.zeros(max_batch, dtype=bool)
         self.slot_meta: list[_Slot | None] = [None] * max_batch
 
@@ -336,16 +301,8 @@ class SlotScheduler:
 
     def _init_state(self, row_cache, max_new_cap: int) -> None:
         self.max_new_cap = max_new_cap
-        cache = jax.tree_util.tree_map(
-            lambda r: jnp.zeros(_pool_shape(np.shape(r), self.slots), r.dtype),
-            row_cache,
-        )
-        layers = models.decode_attention_layers(self.engine.cfg, cache)
-        if layers:
-            k = cache["k"]
-            self._attn = (layers, k.shape[2], decode_attention.block_of(k))
         self._state = (
-            cache,
+            models.pool.empty(row_cache, self.slots),
             jnp.zeros((self.slots, 1, 1), jnp.int32),
             jnp.zeros((self.slots, max_new_cap), jnp.int32),
             jnp.zeros((self.slots,), jnp.int32),
@@ -369,15 +326,7 @@ class SlotScheduler:
         eng = self.engine
         with spans.span("serve.admit.prefill",
                         prompt_len=len(req.prompt)) as prefill_span:
-            batch = {"tokens": jnp.asarray(req.prompt, jnp.int32)[None]}
-            if eng.cfg.is_encdec:
-                rng = np.random.default_rng(0)
-                batch["frames"] = jnp.asarray(
-                    rng.standard_normal(
-                        (1, req.prompt.shape[0], eng.cfg.d_model)
-                    ),
-                    jnp.dtype(eng.cfg.dtype),
-                )
+            batch = models.pool.prompt_batch(eng.cfg, req.prompt[None])
             logits, row_cache, *counts = eng._prefill(eng.params, batch)
         with spans.span("serve.admit.sample"):
             row_key = self._request_key(req.rid)
@@ -407,7 +356,7 @@ class SlotScheduler:
             with spans.span("serve.admit.sync"):
                 meta.first_token = int(tok0)
                 if counts:
-                    prefill_span.annotate(**_read_counts(counts[0]))
+                    prefill_span.annotate(**models.pool.read_counts(counts[0]))
         self.slot_meta[idx] = meta
         return idx
 
@@ -421,7 +370,10 @@ class SlotScheduler:
         also reads the step's counters into ``step_counts``, which the
         loop puts on the ``serve.step`` span."""
         cache, toks, out_buf, steps, keys = self._state
-        self.step_counts = self._attn_counts()
+        self.step_counts = models.pool.step_counts(
+            self.engine.cfg, cache,
+            [m.request.prompt.shape[0] + m.steps_done - 1
+             for m in self.slot_meta if m is not None])
         with spans.span("serve.step.dispatch"):
             cache, toks, out_buf, steps, keys, *counts = self._step_fn(
                 self.engine.params, cache, toks, out_buf, steps, keys,
@@ -434,7 +386,7 @@ class SlotScheduler:
                 feed = np.asarray(toks)      # (slots, 1, 1): just-sampled
                 if counts:
                     self.step_counts = {**(self.step_counts or {}),
-                                        **_read_counts(counts[0])}
+                                        **models.pool.read_counts(counts[0])}
             deltas = [
                 TokenDelta(
                     rid=meta.request.rid,
@@ -448,23 +400,6 @@ class SlotScheduler:
             if meta is not None:
                 meta.steps_done += 1
         return deltas
-
-    def _attn_counts(self) -> dict | None:
-        """The decode-attention kernel's reads in the next step, from the
-        active rows' positions: ``attn_kv_read``, the positions it is
-        handed (each row's ``min(pos + 1, S)``, rounded up to its block),
-        and ``attn_kv_held``, the positions the pool holds, both summed
-        over the layers it runs in."""
-        if self._attn is None:
-            return None
-        layers, seq_len, block = self._attn
-        read = sum(
-            -(-min(m.request.prompt.shape[0] + m.steps_done, seq_len)
-              // block) * block
-            for m in self.slot_meta if m is not None
-        )
-        return {"attn_kv_read": layers * read,
-                "attn_kv_held": layers * self.slots * seq_len}
 
     def pop_finished(self, now: float) -> list[Completion]:
         """Retire every slot whose host-mirrored step count hit its target.
@@ -765,11 +700,6 @@ def run_serve_loop(
     report.wall_s = time.monotonic() - t0
     report.programs_lowered = _lowered_since(t0_ns)
     return report
-
-
-def _read_counts(counts) -> dict:
-    """The expert layers' counters, fetched from the device."""
-    return dict(zip(models.moe.COUNTERS, np.asarray(counts).tolist()))
 
 
 def _lowered_since(t0_ns: int) -> dict:

@@ -2,9 +2,10 @@
 different positions, against each row stepped alone.
 
 The slot scheduler keeps its pool in the family's own cache layout (every
-array leaf ``(L, slots, ...)``, ``pos`` one per slot) and steps it with one
-call of ``models.decode_step``. A row of the pool must come out as it would
-alone at B=1 with a scalar ``pos``: the same logits and the same next cache.
+array leaf ``(L, slots, ...)``, ``pos`` one per slot), built, spliced and
+marked by ``models.pool``, and steps it with one call of
+``models.decode_step``. A row of the pool must come out as it would alone
+at B=1 with a scalar ``pos``: the same logits and the same next cache.
 """
 
 from __future__ import annotations
@@ -50,13 +51,13 @@ def _rows(cfg, params, rng):
     return rows, jnp.asarray(toks)
 
 
-def _pool(rows):
-    """The rows as one pool: arrays joined at the batch axis (1), ``pos``
-    one per row."""
-    return jax.tree_util.tree_map(
-        lambda *r: jnp.stack(r) if r[0].ndim == 0 else jnp.concatenate(r, 1),
-        *rows,
-    )
+def _pool(rows, slots=None):
+    """The rows spliced into the first slots of a pool of ``slots`` (as
+    many as there are rows by default)."""
+    pool = models.pool.empty(rows[0], slots or len(rows))
+    for b, row in enumerate(rows):
+        pool = models.pool.splice(pool, row, b)
+    return pool
 
 
 def _row(pool, b):
@@ -67,13 +68,26 @@ def _row(pool, b):
 
 @pytest.mark.parametrize("family", sorted(FAMILIES))
 def test_pool_step_matches_each_row_alone(family):
+    """The rows in a pool with one free slot, stepped as the scheduler
+    steps it: free rows marked by ``models.pool.free_rows``."""
     cfg = get_config(FAMILIES[family], smoke=True)
     params = models.init_params(cfg, 0)
     rows, toks = _rows(cfg, params, np.random.default_rng(7))
     step = jax.jit(lambda p, c, t: models.decode_step(cfg, p, c, t))
 
-    logits, pool = step(params, _pool(rows), toks)
-    assert pool["pos"].shape == (len(rows),)
+    @jax.jit
+    def pool_step(p, c, t, active):
+        c, free = models.pool.free_rows(cfg, c, active)
+        return models.decode_step(cfg, p, c, t, **free)[:2]
+
+    slots = len(rows) + 1
+    active = jnp.arange(slots) < len(rows)
+    logits, pool = pool_step(params, _pool(rows, slots),
+                             jnp.concatenate([toks, toks[:1]]), active)
+    assert pool["pos"].shape == (slots,)
+    # the free row (pos 0 in the zero pool) was marked where it attends
+    free_pos = models.FREE_POS if models.pool.attends(cfg) else 0
+    assert int(pool["pos"][-1]) == free_pos + 1
     for b, row in enumerate(rows):
         want_logits, want = step(params, row, toks[b:b + 1])
         assert want["pos"].shape == ()
@@ -170,6 +184,34 @@ def test_midflight_admissions_serve_the_same_tokens(family):
     assert [done[i].tokens.tolist() for i in range(5)] == SERVED[family]
 
 
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_the_step_calls_decode_step_as_the_benchmark_patches_it(
+        family, monkeypatch):
+    """The serve loop looks ``decode_step`` up on ``repro.models`` when it
+    traces its step, and calls it with exactly four positional arguments
+    for a family without experts: a wrapper of that form installed there
+    serves the tokens the loop serves unpatched. A model with experts is
+    also handed ``active`` and ``counters=True``."""
+    real = models.decode_step
+    calls = []
+
+    def four(cfg, params, cache, tokens):
+        calls.append({})
+        return real(cfg, params, cache, tokens)
+
+    def routed(cfg, params, cache, tokens, *, active, counters):
+        calls.append({"active": active.shape, "counters": counters})
+        return real(cfg, params, cache, tokens, active=active,
+                    counters=counters)
+
+    moe = get_config(FAMILIES[family], smoke=True).is_moe
+    monkeypatch.setattr(models, "decode_step", routed if moe else four)
+    test_midflight_admissions_serve_the_same_tokens(family)
+    assert calls
+    assert all(c == ({"active": (3,), "counters": True} if moe else {})
+               for c in calls)
+
+
 # (architecture, KV heads) of a tiny grouped-query and a tiny multi-head
 # model, and the tokens the serve loop below gave for them when attention
 # read the whole pool through XLA
@@ -216,3 +258,22 @@ def test_step_spans_count_the_kernels_reads(heads, monkeypatch):
         L * (128 + 128), L * (256 + 128), L * (256 + 128)]
     assert [s["attn_kv_held"] for s in steps] == [L * 3 * 256] * 3
     assert [done[i].tokens.tolist() for i in range(2)] == tokens
+
+
+@pytest.mark.parametrize("family", ["dense", "hybrid", "mamba2", "windowed"])
+def test_step_counts_count_only_the_layers_that_run_the_kernel(family):
+    """``models.pool.step_counts`` on a pool of 4 slots of 32 positions
+    (one block of 32) with rows at positions 4 and 12: each row is handed
+    one block in each layer that runs the kernel; a step with no such
+    layer (mamba2's and the hybrid's) counts nothing."""
+    cfg = get_config(FAMILIES[family], smoke=True)
+    params = models.init_params(cfg, 0)
+    rows, _ = _rows(cfg, params, np.random.default_rng(5))
+    got = models.pool.step_counts(cfg, _pool(rows, 4), [4, 12])
+    if family in ("hybrid", "mamba2"):
+        assert got is None
+        return
+    # windowed: one of its two layers attends to the whole cache
+    layers = {"dense": 2, "windowed": 1}[family]
+    assert got == {"attn_kv_read": layers * 2 * 32,
+                   "attn_kv_held": layers * 4 * 32}
