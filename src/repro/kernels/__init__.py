@@ -12,6 +12,9 @@
     decode_attention — one token per row against one layer of the stacked
                        slot pool, read in place, each row's blocks only up
                        to its length (the decode step's attention)
+    ssm_decode       — one token's step of one layer of the stacked SSM
+                       state, each live row read once and written once in
+                       place, free rows untouched (the decode step's SSM)
 
 Each package: <name>.py (pl.pallas_call + BlockSpec), ops.py (jit wrapper),
 ref.py (pure-jnp oracle). Validated on CPU with interpret=True; compiled
@@ -24,7 +27,8 @@ from . import (
     moe_gmm,
     paged_reloc_copy,
     rmsnorm,
+    ssm_decode,
 )
 
 __all__ = ["decode_attention", "flash_attention", "moe_gmm",
-           "paged_reloc_copy", "rmsnorm"]
+           "paged_reloc_copy", "rmsnorm", "ssm_decode"]

@@ -206,9 +206,9 @@ def decode_step(cfg, params, cache, tokens):
     """tokens (B,1) + cache -> (logits (B,1,V), cache'). ``pos`` is a
     scalar or one position per row (B,). Every cache is written in place:
     each attention invocation its new token's K and V at each row's
-    ``pos % S``, each mamba layer its conv state, and the SSM states all
-    at once after the last layer (``mamba2.decode_layers``). A row whose
-    ``pos`` is ``FREE_POS`` (a free slot) reads no cache."""
+    ``pos % S``, each mamba layer its conv state and its SSM state
+    (``mamba2.decode_layers``). A row whose ``pos`` is ``FREE_POS`` (a
+    free slot) reads no cache and keeps its SSM state as it was."""
     B = tokens.shape[0]
     hd = _hd(cfg)
     pos = cache["pos"] + 1
@@ -220,8 +220,9 @@ def decode_step(cfg, params, cache, tokens):
     x0 = x
     sin, cos = rope_angles(rpos[:, None], hd, cfg.rope_theta)
     g = cfg.attn_every
-    k_all, v_all, conv = cache["k"], cache["v"], cache["conv"]
-    steps = []
+    live = mamba2.live_rows(cache, B)
+    k_all, v_all = cache["k"], cache["v"]
+    conv, ssm = cache["conv"], cache["ssm"]
     for i, lo in enumerate(range(0, cfg.num_layers, g)):
         # shared attention with this invocation's cache
         h = jnp.concatenate([x, x0], -1)
@@ -244,13 +245,9 @@ def decode_step(cfg, params, cache, tokens):
         )
         x = x + a @ params["shared_attn/out_proj/w"]
         # mamba group decode
-        x, conv, step = mamba2.decode_layers(
-            cfg, params, x, conv, cache["ssm"], lo, min(lo + g, cfg.num_layers)
+        x, conv, ssm = mamba2.decode_layers(
+            cfg, params, x, conv, ssm, live, lo, min(lo + g, cfg.num_layers)
         )
-        steps.append(step)
     logits = mamba2.logits_fn(cfg, params, x)
-    ssm = mamba2.ssm_advance(
-        cache["ssm"], *(jnp.concatenate(s) for s in zip(*steps))
-    )
     return logits, {"k": k_all, "v": v_all, "conv": conv, "ssm": ssm,
                     "pos": pos}

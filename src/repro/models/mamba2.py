@@ -16,7 +16,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .common import cross_entropy, rms_norm
+from repro.kernels.ssm_decode import ssm_decode
+
+from .common import FREE_POS, cross_entropy, rms_norm
 from .runtime import layer_loop, remat_wrap, scans_unrolled
 from .specs import ParamSpec
 
@@ -253,24 +255,12 @@ def mamba_block(cfg, p, x, *, state=None, conv_state=None, return_state=False):
     return out
 
 
-def ssm_advance(state, da, xdt, B):
-    """The SSM state one token on: ``state`` (..., H, P, N) decayed by
-    ``da`` (..., H), plus the outer product of the token's ``xdt``
-    (..., H, P) and ``B`` (..., G, N), which the H // G heads of a group
-    share."""
-    *lead, H, P, N = state.shape
-    G = B.shape[-2]
-    dbx = jnp.einsum(
-        "...gjp,...gn->...gjpn", xdt.reshape(*lead, G, H // G, P), B
-    )
-    return state * da[..., None, None] + dbx.reshape(state.shape)
-
-
-def mamba_block_decode(cfg, p, x, conv_state, ssm_state):
-    """One-token recurrence. x (B,1,d). Returns the output, the new conv
-    state, and the token's step of the SSM state, ``(da, xdt, B)``:
-    ``ssm_advance(ssm_state, *step)`` is the new SSM state, which the
-    output reads without storing it."""
+def mamba_block_decode(cfg, p, x, conv_state, ssm_all, layer, live):
+    """One-token recurrence. x (B,1,d). ``ssm_all`` is the stacked SSM
+    state of every layer (L, B, H, P, N): the token steps layer ``layer``
+    of each ``live`` row in place (``kernels.ssm_decode``), and reads the
+    new state there. Returns the output, the new conv state and
+    ``ssm_all``."""
     d_inner, H, P, G, N, conv_ch, _ = dims(cfg)
     B_ = x.shape[0]
     h = rms_norm(x, p["norm/scale"], cfg.norm_eps)
@@ -284,15 +274,15 @@ def mamba_block_decode(cfg, p, x, conv_state, ssm_state):
     xs, Bc, Cc = jnp.split(xBC, [d_inner, d_inner + G * N], axis=-1)
     xs = xs.reshape(B_, H, P).astype(jnp.float32)
     Bc = Bc.reshape(B_, G, N).astype(jnp.float32)
-    Cc = jnp.repeat(Cc.reshape(B_, G, N), H // G, axis=1).astype(jnp.float32)
+    Cc = Cc.reshape(B_, G, N).astype(jnp.float32)
     dt = jax.nn.softplus(dt_raw[:, 0].astype(jnp.float32) + p["dt_bias"])  # (B,H)
     A = -jnp.exp(p["A_log"])
-    step = (jnp.exp(dt * A), xs * dt[..., None], Bc)            # da (B,H)
-    new_state = ssm_advance(ssm_state, *step)
-    y = jnp.einsum("bhpn,bhn->bhp", new_state, Cc) + xs * p["D"][:, None]
+    y, ssm_all = ssm_decode(ssm_all, layer, live, jnp.exp(dt * A),
+                            xs * dt[..., None], Bc, Cc)
+    y = y + xs * p["D"][:, None]
     y = y.reshape(B_, 1, d_inner).astype(x.dtype)
     y = rms_norm(y * jax.nn.silu(z), p["gate_norm/scale"], cfg.norm_eps)
-    return x + y @ p["out_proj/w"], new_conv, step
+    return x + y @ p["out_proj/w"], new_conv, ssm_all
 
 
 # --------------------------------------------------------------------------
@@ -382,36 +372,43 @@ def prefill(cfg, params, batch, *, impl: str = "chunked", cache_len=None):
     return logits_fn(cfg, params, x[:, -1:, :]), cache
 
 
-def decode_layers(cfg, params, x, conv, ssm, lo: int, hi: int):
+def decode_layers(cfg, params, x, conv, ssm, live, lo: int, hi: int):
     """Mamba layers ``[lo, hi)`` of one decode step, on the whole stacked
-    caches. ``conv`` is carried through the layer loop, and each layer
-    writes its new conv state into it in place. Each layer reads its SSM
-    state from ``ssm`` and returns its step; the caller advances the
-    whole ``ssm`` cache by the stacked steps once (``ssm_advance``), in
-    place, after the loop: a layer that wrote its state back would have
-    to copy it, since it also reads it."""
+    caches. ``conv`` and ``ssm`` are carried through the layer loop, and
+    each layer writes its new states into them in place: its conv state,
+    and the SSM state of each ``live`` (B,) row, which is read once and
+    written once; a free row's is neither."""
     sub = {n: a[lo:hi] for n, a in _stacked(params).items()}
 
     def body(carry, p, i):
-        h, conv = carry
-        h, c, step = mamba_block_decode(
+        h, conv, ssm = carry
+        h, c, ssm = mamba_block_decode(
             cfg, p, h,
             jax.lax.dynamic_index_in_dim(conv, i, 0, keepdims=False),
-            jax.lax.dynamic_index_in_dim(ssm, i, 0, keepdims=False),
+            ssm, i, live,
         )
-        return (h, jax.lax.dynamic_update_index_in_dim(conv, c, i, 0)), step
+        conv = jax.lax.dynamic_update_index_in_dim(conv, c, i, 0)
+        return (h, conv, ssm), None
 
-    (x, conv), steps = layer_loop(body, (x, conv), sub, lo)
-    return x, conv, steps
+    (x, conv, ssm), _ = layer_loop(body, (x, conv, ssm), sub, lo)
+    return x, conv, ssm
+
+
+def live_rows(cache, batch: int):
+    """(batch,) bool: the rows whose ``pos`` is not ``FREE_POS``, the rows
+    that hold a request."""
+    return jnp.broadcast_to(cache["pos"] != FREE_POS, (batch,))
 
 
 def decode_step(cfg, params, cache, tokens):
     """tokens (B,1) + cache -> (logits (B,1,V), cache'). ``pos`` is a
-    scalar or one position per row (B,); the state does not read it."""
+    scalar or one position per row (B,); the state does not read it, but a
+    row whose ``pos`` is ``FREE_POS`` (a free slot) keeps its state as it
+    was."""
     x = jnp.take(params["embed/tokens"], tokens, axis=0)
-    x, conv, steps = decode_layers(
-        cfg, params, x, cache["conv"], cache["ssm"], 0, cfg.num_layers
+    x, conv, ssm = decode_layers(
+        cfg, params, x, cache["conv"], cache["ssm"],
+        live_rows(cache, tokens.shape[0]), 0, cfg.num_layers,
     )
     logits = logits_fn(cfg, params, x)
-    return logits, {"conv": conv, "ssm": ssm_advance(cache["ssm"], *steps),
-                    "pos": cache["pos"] + 1}
+    return logits, {"conv": conv, "ssm": ssm, "pos": cache["pos"] + 1}

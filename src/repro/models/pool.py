@@ -12,9 +12,10 @@ all the serving layer needs to know of a family to keep such a pool:
   way the family needs, and gives the arguments ``decode_step`` takes for
   them;
 * its counters — ``step_counts`` works out, on the host, what the
-  decode-attention kernel reads in a step, and ``read_counts`` fetches
-  the expert layers' counts, which ``prefill_kwargs`` and ``free_rows``
-  ask for where the model has experts;
+  decode-attention and SSM-state kernels read in a step, and
+  ``read_counts`` fetches the expert layers' counts, which
+  ``prefill_kwargs`` and ``free_rows`` ask for where the model has
+  experts;
 * the rest — ``prompt_batch`` (the prefill's inputs) and ``attends``
   (whether rows need a cache length).
 """
@@ -36,7 +37,7 @@ _SLOT_AXIS = 1
 
 def attends(cfg) -> bool:
     """Whether the family attends over a KV cache: its rows then need a
-    cache length, and a free row is marked by its ``pos``."""
+    cache length."""
     return cfg.family != "ssm"
 
 
@@ -71,13 +72,12 @@ def free_rows(cfg, pool, active):
     marked as holding no request, and the keyword arguments
     ``decode_step`` takes for them: ``(pool, kwargs)``.
 
-    Where the family attends, a free row gets ``pos = FREE_POS``, so it
-    reads no cache. A model with experts is handed ``active``, so that it
-    routes none of a free row's tokens, and ``counters=True``: its step
-    also returns the expert layers' counts (``moe.COUNTERS``). An SSM
-    row's state is stepped whatever it holds."""
-    if attends(cfg):
-        pool = dict(pool, pos=jnp.where(active, pool["pos"], FREE_POS))
+    A free row gets ``pos = FREE_POS``, so that it reads no cache and its
+    SSM state is neither read nor written. A model with experts is handed
+    ``active``, so that it routes none of a free row's tokens, and
+    ``counters=True``: its step also returns the expert layers' counts
+    (``moe.COUNTERS``)."""
+    pool = dict(pool, pos=jnp.where(active, pool["pos"], FREE_POS))
     return pool, (dict(active=active, counters=True) if cfg.is_moe else {})
 
 
@@ -102,23 +102,33 @@ def prompt_batch(cfg, prompts) -> dict:
 
 
 def step_counts(cfg, pool, positions) -> dict | None:
-    """What the decode-attention kernel reads in the next step of ``pool``,
-    from ``positions``, the position each active row decodes at:
-    ``attn_kv_read``, the positions it is handed (each row's
-    ``min(pos + 1, S)``, rounded up to its block), and ``attn_kv_held``,
-    the positions the pool holds, both summed over the layers it runs in.
-    None where no layer of the step runs the kernel."""
-    if api._mod(cfg) is not transformer:
-        return None
-    k = pool["k"]
-    slots, seq_len = k.shape[_SLOT_AXIS], k.shape[2]
-    layers = transformer.kernel_layers(cfg, seq_len)
-    if not layers:
-        return None
-    block = decode_attention.block_of(k)
-    read = sum(-(-min(p + 1, seq_len) // block) * block for p in positions)
-    return {"attn_kv_read": layers * read,
-            "attn_kv_held": layers * slots * seq_len}
+    """What the step's kernels read in the next step of ``pool``, from
+    ``positions``, the position each active row decodes at.
+
+    Where the decode-attention kernel runs: ``attn_kv_read``, the
+    positions it is handed (each row's ``min(pos + 1, S)``, rounded up to
+    its block), and ``attn_kv_held``, the positions the pool holds, both
+    summed over the layers it runs in. Where the family keeps an SSM
+    state: ``ssm_rows_stepped``, the rows whose state the step reads and
+    writes (the active ones), and ``ssm_rows_held``, the rows the pool
+    holds, both summed over the SSM layers. None where the step has
+    neither."""
+    counts = {}
+    if "ssm" in pool:
+        layers, slots = pool["ssm"].shape[:2]
+        counts.update(ssm_rows_stepped=layers * len(positions),
+                      ssm_rows_held=layers * slots)
+    if api._mod(cfg) is transformer:
+        k = pool["k"]
+        slots, seq_len = k.shape[_SLOT_AXIS], k.shape[2]
+        layers = transformer.kernel_layers(cfg, seq_len)
+        if layers:
+            block = decode_attention.block_of(k)
+            read = sum(-(-min(p + 1, seq_len) // block) * block
+                       for p in positions)
+            counts.update(attn_kv_read=layers * read,
+                          attn_kv_held=layers * slots * seq_len)
+    return counts or None
 
 
 def read_counts(counts) -> dict:

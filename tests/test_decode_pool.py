@@ -85,9 +85,8 @@ def test_pool_step_matches_each_row_alone(family):
     logits, pool = pool_step(params, _pool(rows, slots),
                              jnp.concatenate([toks, toks[:1]]), active)
     assert pool["pos"].shape == (slots,)
-    # the free row (pos 0 in the zero pool) was marked where it attends
-    free_pos = models.FREE_POS if models.pool.attends(cfg) else 0
-    assert int(pool["pos"][-1]) == free_pos + 1
+    # the free row (pos 0 in the zero pool) was marked free
+    assert int(pool["pos"][-1]) == models.FREE_POS + 1
     for b, row in enumerate(rows):
         want_logits, want = step(params, row, toks[b:b + 1])
         assert want["pos"].shape == ()
@@ -119,6 +118,51 @@ def test_unrolled_pool_step_matches_the_scan(family):
     for name in a:
         np.testing.assert_allclose(np.asarray(a[name]), np.asarray(b[name]),
                                    rtol=1e-5, atol=1e-5, err_msg=name)
+
+
+@pytest.mark.parametrize("family", ["mamba2", "hybrid"])
+def test_a_free_rows_ssm_state_is_neither_read_nor_written(family,
+                                                           monkeypatch):
+    """One step of a pool with two free rows whose SSM state holds noise,
+    marked free by ``models.pool.free_rows``: their state comes out bit for
+    bit as it went in, and each live row's is its state decayed by the
+    token's ``da`` plus the outer product of its ``xdt`` and its group's
+    ``B``, in every layer."""
+    from repro.models import mamba2
+
+    cfg = get_config(FAMILIES[family], smoke=True)
+    params = models.init_params(cfg, 0)
+    rows, toks = _rows(cfg, params, np.random.default_rng(9))
+    slots = len(rows) + 2
+    active = np.arange(slots) < len(rows)
+    pool = _pool(rows, slots)
+    noise = np.random.default_rng(1).standard_normal(pool["ssm"].shape)
+    pool["ssm"] = jnp.where(active[None, :, None, None, None], pool["ssm"],
+                            jnp.asarray(noise, jnp.float32))
+    steps = {}
+    real = mamba2.ssm_decode
+
+    def record(ssm_all, layer, live, da, xdt, B, C):
+        steps[layer] = (da, xdt, B)
+        return real(ssm_all, layer, live, da, xdt, B, C)
+
+    monkeypatch.setattr(mamba2, "ssm_decode", record)
+    marked, free = models.pool.free_rows(cfg, pool, jnp.asarray(active))
+    with unroll_scans():
+        _, got = models.decode_step(cfg, params, marked,
+                                    jnp.concatenate([toks, toks[:2]]), **free)
+
+    before, after = np.asarray(pool["ssm"]), np.asarray(got["ssm"])
+    assert (after[:, ~active] == before[:, ~active]).all()
+    assert sorted(steps) == list(range(cfg.num_layers))
+    heads = before.shape[2]
+    for layer, (da, xdt, B) in steps.items():
+        da, xdt, B = (np.asarray(a)[active] for a in (da, xdt, B))
+        B = np.repeat(B, heads // B.shape[1], axis=1)
+        want = (before[layer, active] * da[:, :, None, None]
+                + xdt[..., None] * B[:, :, None, :])
+        np.testing.assert_allclose(after[layer, active], want, rtol=1e-6,
+                                   atol=1e-6, err_msg=f"layer {layer}")
 
 
 # The tokens a sampled serve loop with admissions mid-flight produced when
@@ -260,20 +304,31 @@ def test_step_spans_count_the_kernels_reads(heads, monkeypatch):
     assert [done[i].tokens.tolist() for i in range(2)] == tokens
 
 
-@pytest.mark.parametrize("family", ["dense", "hybrid", "mamba2", "windowed"])
+# what ``step_counts`` gives for a pool of 4 slots of 32 positions with
+# rows at positions 4 and 12, by family: the attention counters of the
+# layers that run the decode-attention kernel (a block of 32 positions
+# each; windowed: one of its two layers attends to the whole cache), and
+# two SSM rows stepped of four held in each SSM layer (the hybrid's four,
+# whose shared attention reads its cache through XLA: no attention
+# counters)
+STEP_COUNTS = {
+    "dense": {"attn_kv_read": 2 * 2 * 32, "attn_kv_held": 2 * 4 * 32},
+    "windowed": {"attn_kv_read": 1 * 2 * 32, "attn_kv_held": 1 * 4 * 32},
+    "mamba2": {"ssm_rows_stepped": 2 * 2, "ssm_rows_held": 2 * 4},
+    "hybrid": {"ssm_rows_stepped": 4 * 2, "ssm_rows_held": 4 * 4},
+}
+
+
+@pytest.mark.parametrize("family", sorted(STEP_COUNTS))
 def test_step_counts_count_only_the_layers_that_run_the_kernel(family):
     """``models.pool.step_counts`` on a pool of 4 slots of 32 positions
     (one block of 32) with rows at positions 4 and 12: each row is handed
-    one block in each layer that runs the kernel; a step with no such
-    layer (mamba2's and the hybrid's) counts nothing."""
+    one block in each layer that runs the decode-attention kernel, and
+    each active row's SSM state is stepped in each SSM layer; the
+    attention families count no SSM rows, and the SSM families no
+    attention."""
     cfg = get_config(FAMILIES[family], smoke=True)
     params = models.init_params(cfg, 0)
     rows, _ = _rows(cfg, params, np.random.default_rng(5))
     got = models.pool.step_counts(cfg, _pool(rows, 4), [4, 12])
-    if family in ("hybrid", "mamba2"):
-        assert got is None
-        return
-    # windowed: one of its two layers attends to the whole cache
-    layers = {"dense": 2, "windowed": 1}[family]
-    assert got == {"attn_kv_read": layers * 2 * 32,
-                   "attn_kv_held": layers * 4 * 32}
+    assert got == STEP_COUNTS[family]
